@@ -4,17 +4,18 @@ Two views:
   (a) analytical α-β model times on TPU-v5e link constants across message
       sizes — reproducing the paper's regime analysis (butterfly for small
       γm, ring/rabenseifner for large), and
-  (b) measured wall time of our shard_map schedules on 8 host devices
-      (spawned subprocess — this process stays single-device).
+  (b) measured wall time of our shard_map schedules on 8 virtual CPU devices
+      (spawned subprocess pinned to the CPU — this process stays
+      single-device). A failed child fails this module.
 """
 import json
-import os
 import subprocess
 import sys
 import textwrap
 
 from benchmarks.common import emit
 from repro.core import costmodel as cm
+from repro.launch.mesh import cpu_devices_env
 
 L, G = 1e-6, 1.0 / 50e9   # ICI-ish constants
 
@@ -39,9 +40,10 @@ def measured():
         import json, time
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.core.compat import shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.core import collectives as coll
-        mesh = jax.make_mesh((8,), ('x',))
+        mesh = make_mesh((8,), ('x',))
         out = {}
         x = jnp.ones((8, 262144), jnp.float32)
         for alg in coll.ALGORITHMS:
@@ -55,17 +57,16 @@ def measured():
             out[alg] = (time.perf_counter() - t0) / 5 * 1e6
         print('RESULT ' + json.dumps(out))
     """)
-    env = {**os.environ,
-           "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
-           "PYTHONPATH": "src"}
+    env = {**cpu_devices_env(8), "PYTHONPATH": "src"}
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, timeout=600, env=env)
     for line in r.stdout.splitlines():
-        if line.startswith("RESULT "):
+        if r.returncode == 0 and line.startswith("RESULT "):
             for alg, us in json.loads(line[7:]).items():
                 emit(f"fig6/measured_8dev_1M/{alg}", us, "host-CPU emulation")
             return
-    emit("fig6/measured_8dev_1M", None, f"subprocess failed: {r.stderr[-200:]}")
+    raise RuntimeError(f"fig6 8-device child failed (rc={r.returncode}):\n"
+                       f"{r.stderr[-2000:]}")
 
 
 def main():

@@ -9,7 +9,7 @@ from benchmarks.common import emit
 from repro.configs.base import ModelConfig
 from repro.core import parallelism as par
 from repro.data.pipeline import SyntheticLM
-from repro.launch.mesh import make_host_mesh
+from repro.launch.mesh import make_mesh
 from repro.optim import make_optimizer
 from repro.train import trainer
 
@@ -20,7 +20,7 @@ def main():
                       vocab_size=64, loss_chunk=32, attn_chunk=32, remat=False)
     token_budget = 64 * 64 * 16          # fixed across batch sizes
     seq = 64
-    plan = par.make_plan("dp", make_host_mesh())
+    plan = par.make_plan("dp", make_mesh())
     for B in (4, 16, 64):
         steps = token_budget // (B * seq)
         opt = make_optimizer("adam", lr=3e-3)

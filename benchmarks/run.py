@@ -59,6 +59,8 @@ def main(argv=None) -> None:
                          "tokens/s and state-KB/slot fp32 vs int8, kernel "
                          "dequant overhead, fixed-budget pool capacity)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failures = 0
     for mod_name in MODULES:

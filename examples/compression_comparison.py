@@ -9,7 +9,6 @@ Reproduces the survey's central compression claim: with local gradient
 accumulation (error feedback), even 1-bit / top-1% gradients track the
 uncompressed loss curve closely while moving 30–2000x fewer bytes.
 """
-import os
 import subprocess
 import sys
 
@@ -18,14 +17,14 @@ import jax, jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.core.compression import make_compressor
 from repro.data.pipeline import SyntheticLM
-from repro.launch.mesh import make_host_mesh
+from repro.launch.mesh import make_mesh
 from repro.optim import make_optimizer
 from repro.train import trainer
 
 cfg = ModelConfig(name="c", family="dense", num_layers=2, d_model=64,
                   num_heads=4, num_kv_heads=4, head_dim=16, d_ff=128,
                   vocab_size=64, loss_chunk=32, attn_chunk=32, remat=False)
-mesh = make_host_mesh((len(jax.devices()),), ("data",))
+mesh = make_mesh((len(jax.devices()),), ("data",))
 data = SyntheticLM(cfg.vocab_size, 64, noise=0.05)
 batches = list(data.batches(16, 60))
 n_params = cfg.param_count()
@@ -52,12 +51,12 @@ print("DONE")
 
 
 def main():
-    env = {**os.environ, "PYTHONPATH": "src",
-           "XLA_FLAGS": "--xla_force_host_platform_device_count=4"}
+    from repro.launch.mesh import cpu_devices_env
+    env = {**cpu_devices_env(4), "PYTHONPATH": "src"}
     r = subprocess.run([sys.executable, "-c", CODE], env=env, text=True,
                        capture_output=True, timeout=1800)
     print(r.stdout)
-    if "DONE" not in r.stdout:
+    if r.returncode != 0 or "DONE" not in r.stdout:
         print(r.stderr[-2000:])
         sys.exit(1)
 

@@ -6,7 +6,6 @@ Runs an MLP forward through the microbatch pipeline schedule, verifies it
 against the sequential computation, and prints the bubble fraction predicted
 by the paper's latency analysis vs the schedule's actual idle slots.
 """
-import os
 import subprocess
 import sys
 
@@ -14,9 +13,9 @@ CODE = """
 import jax, jax.numpy as jnp, numpy as np
 from repro.core.pipeline import pipeline_forward, num_pipeline_rounds
 from repro.core.costmodel import pipeline_bubble_fraction
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((4,), ("stage",),
-                     axis_types=(jax.sharding.AxisType.Auto,))
+mesh = make_mesh((4,), ("stage",))
 S, M, mb, dim = 4, 8, 16, 32
 key = jax.random.PRNGKey(0)
 W = jax.random.normal(key, (S, dim, dim)) * 0.3
@@ -44,12 +43,12 @@ print("DONE")
 
 
 def main():
-    env = {**os.environ, "PYTHONPATH": "src",
-           "XLA_FLAGS": "--xla_force_host_platform_device_count=4"}
+    from repro.launch.mesh import cpu_devices_env
+    env = {**cpu_devices_env(4), "PYTHONPATH": "src"}
     r = subprocess.run([sys.executable, "-c", CODE], env=env, text=True,
                        capture_output=True, timeout=900)
     print(r.stdout)
-    if "DONE" not in r.stdout:
+    if r.returncode != 0 or "DONE" not in r.stdout:
         print(r.stderr[-2000:])
         sys.exit(1)
 

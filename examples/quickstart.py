@@ -12,7 +12,7 @@ import jax.numpy as jnp
 from repro.configs.base import get_config, reduced
 from repro.core import parallelism as par
 from repro.data.pipeline import SyntheticLM, shard_batch
-from repro.launch.mesh import make_host_mesh
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as T
 from repro.optim import make_optimizer
 from repro.serving import serve
@@ -24,7 +24,7 @@ def main():
     cfg = reduced(get_config("yi-9b"))
     print(f"arch={cfg.name} params={cfg.param_count():,}")
 
-    mesh = make_host_mesh()
+    mesh = make_mesh()
     plan = par.make_plan("dp", mesh)
     opt = make_optimizer("adam", lr=3e-3, grad_clip=1.0)
     state = trainer.init_state(cfg, opt, jax.random.PRNGKey(0))

@@ -31,7 +31,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core import parallelism as par
 from repro.data.pipeline import copy_task
-from repro.launch.mesh import make_host_mesh
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as T
 from repro.optim import make_optimizer
 from repro.serving import serve
@@ -44,7 +44,7 @@ def main():
     cfg = ModelConfig(name="copy", family="dense", num_layers=2, d_model=128,
                       num_heads=4, num_kv_heads=4, head_dim=32, d_ff=256,
                       vocab_size=32, loss_chunk=32, attn_chunk=32, remat=False)
-    plan = par.make_plan("dp", make_host_mesh())
+    plan = par.make_plan("dp", make_mesh())
     opt = make_optimizer("adam", lr=2e-3, grad_clip=1.0)
     state = trainer.init_state(cfg, opt, jax.random.PRNGKey(0))
     step = jax.jit(trainer.make_train_step(cfg, opt, plan))

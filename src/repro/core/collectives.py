@@ -28,12 +28,6 @@ from jax import lax
 ALGORITHMS = ("psum", "ring", "tree", "butterfly", "rabenseifner")
 
 
-def _axis_size(axis):
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return int(lax.psum(1, axis))   # older jax: psum of a constant is static
-
-
 def _is_pow2(n):
     return n & (n - 1) == 0
 
@@ -47,7 +41,7 @@ def allreduce_sum(x, axis, algorithm="psum"):
     """Allreduce-sum of `x` over mesh axis `axis` (inside shard_map)."""
     if algorithm == "psum":
         return lax.psum(x, axis)
-    P = _axis_size(axis)
+    P = lax.axis_size(axis)
     if P == 1:
         return x
     if algorithm == "ring":
@@ -64,7 +58,7 @@ def allreduce_sum(x, axis, algorithm="psum"):
 
 
 def allreduce_mean(x, axis, algorithm="psum"):
-    return allreduce_sum(x, axis, algorithm) / _axis_size(axis)
+    return allreduce_sum(x, axis, algorithm) / lax.axis_size(axis)
 
 
 # ------------------------------------------------------------------ butterfly
@@ -121,26 +115,32 @@ def _ring_allreduce(x, axis, P):
     v = jnp.concatenate([f.reshape(-1) for f in flat]) if len(flat) > 1 else flat[0].reshape(-1)
     n = v.size
     pad = (-n) % P
-    v = jnp.pad(v, (0, pad)).reshape(P, (n + pad) // P)
+    v = jnp.pad(v, (0, pad))
+    m = (n + pad) // P
 
     idx = lax.axis_index(axis)
     perm_next = _perm(P, 1)  # send to rank+1
 
+    # chunks are sliced from the flat vector: indexing rows of a (P, m)
+    # reshape instead makes the TPU compiler's time grow with m (minutes
+    # for an embedding-sized gradient)
+    def chunk(i):
+        return lax.dynamic_slice_in_dim(v, (i % P) * m, m)
+
     # reduce-scatter ring: after P−1 steps rank r owns the full sum of chunk r
-    buf = v[(idx - 1) % P]
+    buf = chunk(idx - 1)
     for k in range(1, P - 1):
         buf = lax.ppermute(buf, axis, perm_next)
-        buf = buf + v[(idx - k - 1) % P]
-    owned = lax.ppermute(buf, axis, perm_next) + v[idx]
+        buf = buf + chunk(idx - k - 1)
+    owned = lax.ppermute(buf, axis, perm_next) + chunk(idx)
 
     # allgather ring: circulate owned chunks P−1 steps
     cur = owned
-    out = jnp.zeros_like(v)
-    out = out.at[idx].set(owned)
+    out = lax.dynamic_update_slice_in_dim(jnp.zeros_like(v), owned, idx * m, 0)
     for k in range(1, P):
         cur = lax.ppermute(cur, axis, perm_next)
-        out = out.at[(idx - k) % P].set(cur)
-    res = out.reshape(-1)[:n]
+        out = lax.dynamic_update_slice_in_dim(out, cur, ((idx - k) % P) * m, 0)
+    res = out[:n]
     if len(flat) == 1:
         return res.reshape(shapes[0])
     outs = []

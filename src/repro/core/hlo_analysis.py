@@ -390,8 +390,6 @@ def cost_analysis_dict(compiled) -> dict:
         ca = compiled.cost_analysis()
     except Exception:
         return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     return dict(ca) if ca else {}
 
 

@@ -16,10 +16,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-from repro.core.compat import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax import lax, shard_map
+from jax.sharding import PartitionSpec as P
 
 
 def pipeline_forward(stage_fn, params_stacked, x_microbatches, mesh, axis="stage"):

@@ -6,20 +6,15 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import platform
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-@partial(jax.jit, static_argnames=("causal", "window", "block_q", "block_k",
-                                   "interpret"))
+@partial(jax.jit, static_argnames=("causal", "window", "block_q", "block_k"))
 def flash_attention(q, k, v, *, causal=True, window=None, block_q=256,
-                    block_k=256, interpret=None):
+                    block_k=256):
     """q: (B, S, H, hd); k, v: (B, S, Hkv, hd) with H % Hkv == 0.
     Returns (B, S, H, hd)."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
     B, S, H, hd = q.shape
     Hkv = k.shape[2]
     rep = H // Hkv
@@ -29,5 +24,6 @@ def flash_attention(q, k, v, *, causal=True, window=None, block_q=256,
     to_bh = lambda t: t.transpose(0, 2, 1, 3).reshape(B * H, S, hd)
     out = flash_attention_pallas(
         to_bh(q), to_bh(k), to_bh(v), causal=causal, window=window,
-        block_q=block_q, block_k=block_k, interpret=interpret)
+        block_q=block_q, block_k=block_k,
+        interpret=platform.interpret())
     return out.reshape(B, H, S, hd).transpose(0, 2, 1, 3)

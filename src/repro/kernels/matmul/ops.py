@@ -1,22 +1,16 @@
-"""jit'd public wrapper for the blocked GEMM kernel.
-
-On non-TPU backends (this CPU container) `interpret=True` executes the kernel
-body in Python — the validation mode used by the kernel test sweeps."""
+"""jit'd public wrapper for the blocked GEMM kernel (interpret mode off TPU,
+see ``repro.kernels.platform``)."""
 from __future__ import annotations
 
 from functools import partial
 
 import jax
 
+from repro.kernels import platform
 from repro.kernels.matmul.kernel import matmul_pallas
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-@partial(jax.jit, static_argnames=("block_m", "block_n", "block_k", "interpret"))
-def matmul(a, b, *, block_m=256, block_n=256, block_k=512, interpret=None):
-    interpret = (not _on_tpu()) if interpret is None else interpret
+@partial(jax.jit, static_argnames=("block_m", "block_n", "block_k"))
+def matmul(a, b, *, block_m=256, block_n=256, block_k=512):
     return matmul_pallas(a, b, block_m=block_m, block_n=block_n,
-                         block_k=block_k, interpret=interpret)
+                         block_k=block_k, interpret=platform.interpret())

@@ -8,20 +8,15 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import platform
 from repro.kernels.quantize.kernel import quantize_pallas
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-@partial(jax.jit, static_argnames=("bits", "block", "mode", "interpret"))
-def quantize_blocks(flat, key=None, *, bits=8, block=256, mode="stochastic",
-                    interpret=None):
+@partial(jax.jit, static_argnames=("bits", "block", "mode"))
+def quantize_blocks(flat, key=None, *, bits=8, block=256, mode="stochastic"):
     """flat: (n,) f32 gradient; returns (q (rows, block) int8, scales (rows,),
     n) — padded to a block multiple. mode="nearest" is deterministic (no key
     needed); "stochastic" keeps E[dequant(quant(g))] = g for gradients."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
     n = flat.shape[0]
     pad = (-n) % block
     x = jnp.pad(flat.astype(jnp.float32), (0, pad)).reshape(-1, block)
@@ -36,7 +31,7 @@ def quantize_blocks(flat, key=None, *, bits=8, block=256, mode="stochastic",
             raise ValueError("stochastic mode needs a PRNG key")
         noise = jax.random.uniform(key, x.shape)
     q, s = quantize_pallas(x, noise, bits=bits, block_rows=block_rows,
-                           mode=mode, interpret=interpret)
+                           mode=mode, interpret=platform.interpret())
     return q, s
 
 

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run (deliverable (e)) + roofline extraction (deliverable (g)).
 
 For a given (architecture × input shape × mesh × plan):
@@ -11,8 +8,8 @@ For a given (architecture × input shape × mesh × plan):
   4. parse collective bytes from the optimized HLO,
   5. emit roofline terms + MODEL_FLOPS ratio as JSON.
 
-Run one combination per process (the 512 fake devices are locked in at jax
-init):  PYTHONPATH=src python -m repro.launch.dryrun --arch yi-9b \
+Run one combination per process (the 512 virtual CPU devices are locked in
+at jax init):  PYTHONPATH=src python -m repro.launch.dryrun --arch yi-9b \
             --shape train_4k --mesh single --plan dp_tp
 """
 import argparse
@@ -30,7 +27,7 @@ from repro.configs.base import SHAPES, get_config
 from repro.core import costmodel as cm
 from repro.core import hlo_analysis as ha
 from repro.core import parallelism as par
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_production_mesh, use_cpu_devices
 from repro.launch.specs import input_specs, shape_applicable
 from repro.models import transformer as T
 from repro.optim import make_optimizer
@@ -179,6 +176,7 @@ def main():
     ap.add_argument("--out", default=None)
     ap.add_argument("--accum", type=int, default=1)
     args = ap.parse_args()
+    use_cpu_devices(512)
     try:
         rec = run(args.arch, args.shape, args.mesh, args.plan, args.out,
                   accum_steps=args.accum)

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """§Perf profiler: lower+compile one combo and print trip-weighted top ops by
 HBM traffic / FLOPs / collective bytes — the evidence for each hypothesis.
 
@@ -12,7 +9,7 @@ import argparse
 from repro.configs.base import SHAPES, get_config
 from repro.core import hlo_analysis as ha
 from repro.launch.dryrun import lower_combo
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_production_mesh, use_cpu_devices
 
 
 def main():
@@ -25,6 +22,7 @@ def main():
     ap.add_argument("--n", type=int, default=20)
     ap.add_argument("--collectives", action="store_true")
     args = ap.parse_args()
+    use_cpu_devices(512)
 
     cfg = get_config(args.arch)
     mesh = make_production_mesh()

@@ -77,6 +77,8 @@ def main():
                            "error": "timeout"}, f)
             print(f"[{i+1}/{len(combos)}] {arch} {shape} {mesh}: TIMEOUT", flush=True)
     print(f"done in {time.time()-t_start:.0f}s: ok={n_ok} skip={n_skip} err={n_err}")
+    if n_err:
+        sys.exit(1)
 
 
 if __name__ == "__main__":

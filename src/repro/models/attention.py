@@ -1,7 +1,7 @@
 """GQA attention: full / sliding-window / local-global, training and decode.
 
 Two XLA execution strategies (the Pallas flash kernel in repro.kernels is the
-TPU-native third, validated in interpret mode):
+TPU-native third):
 
 * ``naive``   — materialize (S, S) scores; fine for smoke tests.
 * ``chunked`` — lax.scan over query chunks with online softmax
@@ -238,15 +238,17 @@ def paged_write(kv, k_new, v_new, block_tables, positions, active, *,
 
 
 def attention_decode_paged(params, x, kv, block_tables, positions, attn_lens,
-                           cfg, *, impl="ref", interpret=None, window=None,
-                           ring_pages=None):
+                           cfg, *, impl=None, window=None, ring_pages=None):
     """One-token decode against a paged KV pool. x: (B,1,D); kv k/v pools
     (N, bs, Hkv, hd); block_tables (B, P); positions (B,) absolute position of
     the incoming token; attn_lens (B,) tokens to attend over INCLUDING the new
     one (0 marks an inactive slot — its write is dropped and its output is
     garbage the engine ignores). window/ring_pages switch sliding-window
     layers to the ring layout (write modulo the ring, attend the last
-    `window` positions). Returns (out (B,1,D), new kv)."""
+    `window` positions). ``impl`` ("kernel" | "ref") defaults to the
+    platform's choice (``repro.kernels.platform``). Returns (out (B,1,D),
+    new kv)."""
+    from repro.kernels import platform
     from repro.kernels.paged_attention import paged_attention, paged_attention_ref
     h, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     B = x.shape[0]
@@ -257,11 +259,10 @@ def attention_decode_paged(params, x, kv, block_tables, positions, attn_lens,
     kv = paged_write(kv, k_new[:, 0], v_new[:, 0], block_tables, positions,
                      attn_lens > 0, ring_pages=ring_pages)
     scales = dict(k_scale=kv.get("k_scale"), v_scale=kv.get("v_scale"))
-    if impl == "kernel":
+    if (impl or platform.paged_attn_impl()) == "kernel":
         out = paged_attention(q[:, 0], kv["k"], kv["v"], block_tables,
                               attn_lens, window=window, positions=positions,
-                              ring_pages=ring_pages, interpret=interpret,
-                              **scales)
+                              ring_pages=ring_pages, **scales)
     else:
         out = paged_attention_ref(q[:, 0], kv["k"], kv["v"], block_tables,
                                   attn_lens, window=window,
@@ -303,8 +304,7 @@ def paged_write_multi(kv, k_new, v_new, block_tables, positions, valid, *,
 
 
 def attention_verify_paged(params, x, kv, block_tables, base, qlims, cfg, *,
-                           impl="ref", interpret=None, window=None,
-                           ring_pages=None):
+                           impl=None, window=None, ring_pages=None):
     """Multi-query speculative verify against a paged KV pool. x: (B,K,D) —
     K draft tokens per sequence, draft j at absolute position base[b] + j.
     qlims (B,): number of draft positions whose K/V may be written this step
@@ -312,7 +312,9 @@ def attention_verify_paged(params, x, kv, block_tables, base, qlims, cfg, *,
     engine discards, and their writes are dropped so rejected-horizon KV
     never lands in the pool. window/ring_pages switch sliding-window layers
     to the ring layout — the ring must be sized with `draft = K - 1` slack
-    (see state_providers.ring_pages). Returns (out (B,K,D), new kv)."""
+    (see state_providers.ring_pages). ``impl`` as in
+    :func:`attention_decode_paged`. Returns (out (B,K,D), new kv)."""
+    from repro.kernels import platform
     from repro.kernels.paged_attention import (paged_attention_verify,
                                                paged_attention_verify_ref)
     h, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
@@ -328,11 +330,10 @@ def attention_verify_paged(params, x, kv, block_tables, base, qlims, cfg, *,
     attn_lens = jnp.where(qlims > 0, base + K, 0)
     newest = attn_lens - 1
     scales = dict(k_scale=kv.get("k_scale"), v_scale=kv.get("v_scale"))
-    if impl == "kernel":
+    if (impl or platform.paged_attn_impl()) == "kernel":
         out = paged_attention_verify(
             q, kv["k"], kv["v"], block_tables, attn_lens, window=window,
-            positions=newest, ring_pages=ring_pages, interpret=interpret,
-            **scales)
+            positions=newest, ring_pages=ring_pages, **scales)
     else:
         out = paged_attention_verify_ref(
             q, kv["k"], kv["v"], block_tables, attn_lens, window=window,

@@ -114,7 +114,7 @@ def moe_apply_ep(params, x, cfg, plan):
     C_loc = ceil(T_loc·K/E·capacity_factor) — drops can differ marginally
     from the global-capacity reference (documented approximation).
     """
-    from repro.core.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = plan.mesh

@@ -377,8 +377,7 @@ def _attn_block(kind, p, lp, h_in, cfg, attn_out):
 
 
 def paged_decode_step(cfg: ModelConfig, params, pool, inputs, block_tables,
-                      positions, attn_lens, *, impl="ref", interpret=None,
-                      draft=0):
+                      positions, attn_lens, *, impl=None, draft=0):
     """One-token decode for a continuous batch of slots, dispatching each
     layer to its state kind. inputs: {"token": (B,)}; block_tables: (B, P);
     positions: (B,) absolute position of each incoming token; attn_lens:
@@ -387,7 +386,9 @@ def paged_decode_step(cfg: ModelConfig, params, pool, inputs, block_tables,
     masked for inactive slots, so slots mid-prefill are never corrupted by
     the batched decode. ``draft`` must match the engine's speculative K-1
     (0 when speculation is off) so ring layers use the same enlarged ring
-    as the verify step. Returns (logits (B,V), new pool)."""
+    as the verify step. ``impl`` picks the paged attention path (None: the
+    platform's, see ``repro.kernels.platform``). Returns (logits (B,V), new
+    pool)."""
     x = _embed_tokens(cfg, params, inputs["token"][:, None])
     kinds = _layer_kinds(cfg)
     skinds = SP.state_kinds(cfg)
@@ -408,8 +409,7 @@ def paged_decode_step(cfg: ModelConfig, params, pool, inputs, block_tables,
                 h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
                 y, kv = A.attention_decode_paged(
                     p["attn"], h, st, block_tables, positions, attn_lens,
-                    cfg, impl=impl, interpret=interpret, window=window,
-                    ring_pages=rp)
+                    cfg, impl=impl, window=window, ring_pages=rp)
                 x = _attn_block(kind, p, lp, x, cfg, y)
                 new_pool[f"l{i}"] = kv
             else:
@@ -447,7 +447,7 @@ def _recurrent_verify_layer(kind, lp, slab, x, cfg, shared):
 
 
 def paged_verify_step(cfg: ModelConfig, params, pool, tokens, block_tables,
-                      base, qlims, *, impl="ref", interpret=None):
+                      base, qlims, *, impl=None):
     """Multi-query speculative verify for a continuous batch of slots.
     tokens: (B, K) — K draft tokens per slot, draft j at absolute position
     `base[b] + j`; qlims: (B,) number of draft positions that may commit
@@ -478,8 +478,7 @@ def paged_verify_step(cfg: ModelConfig, params, pool, tokens, block_tables,
                 h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
                 y, kv = A.attention_verify_paged(
                     p["attn"], h, st, block_tables, base, qlims, cfg,
-                    impl=impl, interpret=interpret, window=window,
-                    ring_pages=rp)
+                    impl=impl, window=window, ring_pages=rp)
                 x = _attn_block(kind, p, lp, x, cfg, y)
                 new_pool[f"l{i}"] = kv
             else:

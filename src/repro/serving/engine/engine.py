@@ -93,8 +93,9 @@ class EngineConfig:
     prefill_chunk: int = 32             # prompt tokens per prefill call
     prefills_per_step: int = 1          # chunks interleaved per engine step
     prefix_caching: bool = True         # alias cached prompt-prefix blocks
-    attn_impl: str = "ref"              # "ref" | "kernel" (Pallas paged-decode)
-    interpret: Optional[bool] = None    # kernel interpret mode (None: off-TPU)
+    attn_impl: Optional[str] = None     # paged attention "kernel" | "ref";
+                                        #   None = the platform's choice
+                                        #   (repro.kernels.platform)
     telemetry: bool = True              # lifecycle tracing + metrics registry
     step_timing: bool = False           # block per device call to time steps
     prefill_buckets: tuple = ()         # chunk-length buckets; () = one
@@ -144,7 +145,7 @@ def _build_step_fns(cfg, e: EngineConfig, plan):
         attn_lens = jnp.where(active, seq_lens + 1, 0)
         logits, pool = T.paged_decode_step(
             cfg, params, pool, {"token": tokens}, tables, positions,
-            attn_lens, impl=e.attn_impl, interpret=e.interpret, draft=draft)
+            attn_lens, impl=e.attn_impl, draft=draft)
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return greedy, logits, seq_lens + active, pool
 
@@ -169,7 +170,7 @@ def _build_step_fns(cfg, e: EngineConfig, plan):
             # greedy acceptance run in-jit (spec.verify_step)
             return SPEC.verify_step(
                 cfg, params, pool, tokens, tables, seq_lens, active, qlims,
-                impl=e.attn_impl, interpret=e.interpret)
+                impl=e.attn_impl)
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def copy_block_fn(pool, src, dst):

@@ -195,7 +195,7 @@ class SpecConfig:
 
 # -------------------------------------------------------------- verify step
 def verify_step(cfg, params, pool, tokens, block_tables, seq_lens, active,
-                qlims, *, impl="ref", interpret=None):
+                qlims, *, impl=None):
     """One speculative verify step over the full slot batch (pure; the
     engine jits it with the pool donated).
 
@@ -215,7 +215,7 @@ def verify_step(cfg, params, pool, tokens, block_tables, seq_lens, active,
     base = jnp.where(active, seq_lens, 0)
     qlims = jnp.where(active, qlims, 0)
     lg, aux = T.paged_verify_step(cfg, params, pool, tokens, block_tables,
-                                  base, qlims, impl=impl, interpret=interpret)
+                                  base, qlims, impl=impl)
     greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)            # (B, K)
     match = (tokens[:, 1:] == greedy[:, :-1]).astype(jnp.int32)   # (B, K-1)
     run = jnp.cumprod(match, axis=1) if match.shape[1] else match

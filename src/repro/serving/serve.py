@@ -59,12 +59,16 @@ class _null:
 # generate() calls don't re-trace
 @functools.lru_cache(maxsize=None)
 def _cached_decode_step(cfg):
-    return jax.jit(lambda p, c, tok, i: T.decode_step(cfg, p, c, {"token": tok}, i))
+    def dense_decode_step(p, c, tok, i):
+        return T.decode_step(cfg, p, c, {"token": tok}, i)
+    return jax.jit(dense_decode_step)
 
 
 @functools.lru_cache(maxsize=None)
 def _cached_prefill_step(cfg):
-    return jax.jit(lambda p, c, toks: T.prefill_step(cfg, p, c, {"tokens": toks}))
+    def dense_prefill_step(p, c, toks):
+        return T.prefill_step(cfg, p, c, {"tokens": toks})
+    return jax.jit(dense_prefill_step)
 
 
 def sample(logits, key, temperature=1.0):
@@ -73,38 +77,47 @@ def sample(logits, key, temperature=1.0):
     return jax.random.categorical(key, logits / temperature, axis=-1).astype(jnp.int32)
 
 
-def generate(cfg, params, prompt_tokens, max_new, *, key=None, temperature=0.0,
-             max_len=None, prefill_mode="auto", kv_quant=None):
-    """Greedy/temperature generation for token-input models.
+def prefill(cfg, params, prompt_tokens, max_len, *, prefill_mode="auto",
+            kv_quant=None):
+    """Fill a dense decode cache of ``max_len`` positions with the prompt.
+    Returns (logits (B, V) of the last prompt token, cache).
 
-    Prefill fills the whole prompt cache in ONE jitted call (`prefill_step`)
-    instead of S0 sequential decode steps; `prefill_mode="loop"` keeps the
-    old token-by-token path as a reference oracle ("auto" falls back to it
-    for recurrent families without a batched prefill). ``kv_quant`` stores
-    the dense KV caches int8 + per-vector scales — the non-paged reference
-    the quantized engine must match token-for-token."""
-    key = key if key is not None else jax.random.PRNGKey(0)
+    The whole prompt goes through ONE jitted call (`prefill_step`);
+    `prefill_mode="loop"` runs it token by token as a reference oracle
+    ("auto" falls back to it for recurrent families without a batched
+    prefill). ``kv_quant`` stores the dense KV caches int8 + per-vector
+    scales."""
     B, S0 = prompt_tokens.shape
-    max_len = max_len or (S0 + max_new)
     cache = T.init_decode_state(cfg, B, max_len, kv_quant=kv_quant)
-    step = _cached_decode_step(cfg)
-
     if prefill_mode not in ("auto", "batched", "loop"):
         raise ValueError(f"unknown prefill_mode {prefill_mode!r}")
     if prefill_mode == "auto":
         prefill_mode = "batched" if T.supports_batched_prefill(cfg) else "loop"
     # labeled spans so device traces separate the prefill and decode phases
     # (the engine labels its phases the same way — serving.telemetry)
-    if prefill_mode == "batched":
-        with jax.profiler.TraceAnnotation("serve/prefill"):
-            logits, cache = _cached_prefill_step(cfg)(params, cache,
-                                                      prompt_tokens)
-    else:  # reference path: token-by-token (any family)
+    with jax.profiler.TraceAnnotation("serve/prefill"):
+        if prefill_mode == "batched":
+            return _cached_prefill_step(cfg)(params, cache, prompt_tokens)
+        step = _cached_decode_step(cfg)     # reference: token-by-token
         logits = None
-        with jax.profiler.TraceAnnotation("serve/prefill"):
-            for i in range(S0):
-                logits, cache = step(params, cache, prompt_tokens[:, i],
-                                     jnp.int32(i))
+        for i in range(S0):
+            logits, cache = step(params, cache, prompt_tokens[:, i],
+                                 jnp.int32(i))
+        return logits, cache
+
+
+def generate(cfg, params, prompt_tokens, max_new, *, key=None, temperature=0.0,
+             max_len=None, prefill_mode="auto", kv_quant=None):
+    """Greedy/temperature generation for token-input models: `prefill`,
+    then one jitted decode step per token. ``kv_quant`` stores the dense KV
+    caches int8 + per-vector scales — the non-paged reference the quantized
+    engine must match token-for-token."""
+    key = key if key is not None else jax.random.PRNGKey(0)
+    B, S0 = prompt_tokens.shape
+    max_len = max_len or (S0 + max_new)
+    logits, cache = prefill(cfg, params, prompt_tokens, max_len,
+                            prefill_mode=prefill_mode, kv_quant=kv_quant)
+    step = _cached_decode_step(cfg)
     out = []
     with jax.profiler.TraceAnnotation("serve/decode"):
         for j in range(max_new):

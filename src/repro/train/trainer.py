@@ -128,7 +128,7 @@ def make_paper_train_step(cfg, optimizer, mesh, *, axis="data",
     feedback residual is carried in the state (survey: "local gradient
     accumulation", Seide et al. / Lin et al.).
     """
-    from repro.core.compat import shard_map
+    from jax import shard_map
     from repro.core import collectives as coll
 
     def local_grads(params, batch):

@@ -12,11 +12,11 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def run_multidev(code: str, devices: int = 8, timeout: int = 600):
-    """Run `code` in a fresh python with N fake devices; returns stdout.
-    The code should print 'PASS' on success."""
-    env = {**os.environ,
-           "XLA_FLAGS": f"--xla_force_host_platform_device_count={devices}",
-           "PYTHONPATH": os.path.join(REPO, "src")}
+    """Run `code` in a fresh python on N virtual CPU devices (JAX pinned to
+    the CPU, device count appended to XLA_FLAGS); returns stdout. The code
+    should print 'PASS' on success."""
+    from repro.launch.mesh import cpu_devices_env
+    env = {**cpu_devices_env(devices), "PYTHONPATH": os.path.join(REPO, "src")}
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                        capture_output=True, text=True, timeout=timeout, env=env)
     assert r.returncode == 0, f"subprocess failed:\n{r.stdout}\n{r.stderr}"

@@ -6,7 +6,7 @@ import pytest
 
 from repro.configs.base import ModelConfig
 from repro.core import parallelism as par
-from repro.launch.mesh import make_host_mesh
+from repro.launch.mesh import make_mesh
 from repro.optim import make_optimizer
 from repro.train import trainer
 from conftest import run_multidev
@@ -24,7 +24,7 @@ class TestGradAccumulation:
         (same mean gradient, modulo f32 accumulation order)."""
         cfg = tiny()
         opt = make_optimizer("sgd", lr=1e-2)
-        plan = par.make_plan("dp", make_host_mesh())
+        plan = par.make_plan("dp", make_mesh())
         key = jax.random.PRNGKey(0)
         batch = {
             "tokens": jax.random.randint(key, (8, 64), 0, cfg.vocab_size),

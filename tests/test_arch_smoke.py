@@ -50,9 +50,9 @@ class TestArchSmoke:
         opt = make_optimizer("adam", lr=1e-3)
         state = trainer.init_state(cfg, opt, jax.random.PRNGKey(0))
         batch = make_batch(cfg, jax.random.PRNGKey(1))
-        from repro.launch.mesh import make_host_mesh
+        from repro.launch.mesh import make_mesh
         from repro.core import parallelism as par
-        plan = par.make_plan("dp", make_host_mesh())
+        plan = par.make_plan("dp", make_mesh())
         step = jax.jit(trainer.make_train_step(cfg, opt, plan))
         new_state, metrics = step(state, batch)
         loss = float(metrics["loss"])

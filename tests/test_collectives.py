@@ -24,9 +24,10 @@ class TestCorrectness8Devices:
         run_multidev("""
             import jax, jax.numpy as jnp, numpy as np
             from jax.sharding import PartitionSpec as P
-            from repro.core.compat import shard_map
+            from jax import shard_map
+            from repro.launch.mesh import make_mesh
             from repro.core import collectives as coll
-            mesh = jax.make_mesh((8,), ('x',))
+            mesh = make_mesh((8,), ('x',))
             x = jnp.arange(8*40, dtype=jnp.float32).reshape(8, 40) * 0.01 - 1.0
             expect = np.broadcast_to(np.asarray(x.sum(0)), (8, 40))
             for alg in coll.ALGORITHMS:
@@ -45,9 +46,10 @@ class TestCorrectness8Devices:
         run_multidev("""
             import jax, jax.numpy as jnp, re
             from jax.sharding import PartitionSpec as P
-            from repro.core.compat import shard_map
+            from jax import shard_map
+            from repro.launch.mesh import make_mesh
             from repro.core import collectives as coll
-            mesh = jax.make_mesh((8,), ('x',))
+            mesh = make_mesh((8,), ('x',))
             x = jnp.zeros((8, 64), jnp.float32)
             for alg, expected in [('ring', 14), ('butterfly', 3),
                                   ('rabenseifner', 6)]:
@@ -67,10 +69,11 @@ class TestCorrectness8Devices:
         run_multidev("""
             import jax, jax.numpy as jnp, numpy as np
             from jax.sharding import PartitionSpec as P
-            from repro.core.compat import shard_map
+            from jax import shard_map
+            from repro.launch.mesh import make_mesh
             from repro.core import collectives as coll
             from repro.core.compression import make_compressor
-            mesh = jax.make_mesh((8,), ('x',))
+            mesh = make_mesh((8,), ('x',))
             comp = make_compressor('int8')
             key = jax.random.PRNGKey(0)
             x = jax.random.normal(key, (8, 256)) * 0.01

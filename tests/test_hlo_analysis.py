@@ -87,9 +87,10 @@ class TestCollectiveBytes:
         run_multidev("""
             import jax, jax.numpy as jnp
             from jax.sharding import PartitionSpec as P
-            from repro.core.compat import shard_map
+            from jax import shard_map
+            from repro.launch.mesh import make_mesh
             from repro.core import hlo_analysis as ha
-            mesh = jax.make_mesh((8,), ('x',))
+            mesh = make_mesh((8,), ('x',))
             x = jnp.zeros((8, 1024), jnp.float32)
             f = shard_map(lambda v: jax.lax.psum(v, 'x'), mesh=mesh,
                           in_specs=P('x'), out_specs=P(), check_vma=False)

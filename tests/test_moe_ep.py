@@ -11,9 +11,10 @@ class TestExpertParallel:
         run_multidev("""
             import jax, jax.numpy as jnp, numpy as np
             from repro.configs.base import ModelConfig
+            from repro.launch.mesh import make_mesh
             from repro.core import parallelism as par
             from repro.models import moe as M
-            mesh = jax.make_mesh((2, 2), ('data', 'model'))
+            mesh = make_mesh((2, 2), ('data', 'model'))
             plan = par.make_plan('dp_tp', mesh)
             cfg = ModelConfig(name='t', family='moe', d_model=32, num_heads=2,
                               num_kv_heads=2, d_ff=64, vocab_size=17,
@@ -36,10 +37,11 @@ class TestExpertParallel:
         run_multidev("""
             import jax, jax.numpy as jnp
             from repro.configs.base import ModelConfig
+            from repro.launch.mesh import make_mesh
             from repro.core import parallelism as par
             from repro.optim import make_optimizer
             from repro.train import trainer
-            mesh = jax.make_mesh((2, 2), ('data', 'model'))
+            mesh = make_mesh((2, 2), ('data', 'model'))
             plan = par.make_plan('dp_tp', mesh)
             cfg = ModelConfig(name='t', family='moe', num_layers=2, d_model=32,
                               num_heads=2, num_kv_heads=2, head_dim=16,

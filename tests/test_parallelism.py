@@ -9,19 +9,12 @@ from jax.sharding import AbstractMesh, PartitionSpec as P
 from repro.core import parallelism as par
 
 
-def _abstract_mesh(shape, axes):
-    try:
-        return AbstractMesh(shape, axes)
-    except TypeError:   # older jax: AbstractMesh(((name, size), ...))
-        return AbstractMesh(tuple(zip(axes, shape)))
-
-
 def mesh_single():
-    return _abstract_mesh((16, 16), ("data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def mesh_multi():
-    return _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 class Leaf:

@@ -25,7 +25,8 @@ class TestPipelineCorrectness:
         run_multidev("""
             import jax, jax.numpy as jnp, numpy as np
             from repro.core.pipeline import pipeline_forward
-            mesh = jax.make_mesh((4,), ('stage',))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((4,), ('stage',))
             key = jax.random.PRNGKey(0)
             W = jax.random.normal(key, (4, 16, 16)) * 0.3
             b = jax.random.normal(jax.random.PRNGKey(1), (4, 16)) * 0.1

@@ -52,7 +52,7 @@ class TestVerifyKernel:
     def test_full_matches_ref(self, H, Hkv, hd, dtype, tol):
         q, kp, vp, tables, lens = _verify_case(
             0, len(self.FULL_LENS), H, Hkv, hd, 64, 4, 8, dtype, self.FULL_LENS)
-        out = paged_attention_verify(q, kp, vp, tables, lens, interpret=True)
+        out = paged_attention_verify(q, kp, vp, tables, lens)
         ref = paged_attention_verify_ref(q, kp, vp, tables, lens)
         np.testing.assert_allclose(np.asarray(out, np.float32),
                                    np.asarray(ref, np.float32), atol=tol)
@@ -69,8 +69,7 @@ class TestVerifyKernel:
             1, 5, 4, 2, 32, 32, bs, rp, dtype, lens)
         pos = jnp.maximum(lens - 1, 0)
         out = paged_attention_verify(q, kp, vp, tables, lens, window=window,
-                                     positions=pos, ring_pages=rp,
-                                     interpret=True)
+                                     positions=pos, ring_pages=rp)
         ref = paged_attention_verify_ref(q, kp, vp, tables, lens,
                                          window=window, positions=pos,
                                          ring_pages=rp)
@@ -118,7 +117,7 @@ class TestVerifyKernel:
         B, bs, P, N = len(self.FULL_LENS), 4, 8, 64
         q, kp, vp, tables, lens = _verify_case(
             4, B, 4, 2, 32, N, bs, P, jnp.float32, self.FULL_LENS)
-        clean = paged_attention_verify(q, kp, vp, tables, lens, interpret=True)
+        clean = paged_attention_verify(q, kp, vp, tables, lens)
         kp2, vp2 = np.array(kp), np.array(vp)
         perm, lens_np = np.asarray(tables), np.asarray(lens)
         referenced = set()
@@ -131,7 +130,7 @@ class TestVerifyKernel:
                     kp2[blk, off] = 1e4
                     vp2[blk, off] = 1e4
         dirty = paged_attention_verify(q, jnp.asarray(kp2), jnp.asarray(vp2),
-                                       tables, lens, interpret=True)
+                                       tables, lens)
         np.testing.assert_allclose(np.asarray(dirty), np.asarray(clean),
                                    atol=1e-6)
 

@@ -10,7 +10,7 @@ import pytest
 from repro.configs.base import ModelConfig
 from repro.core import parallelism as par
 from repro.data.pipeline import SyntheticLM, copy_task
-from repro.launch.mesh import make_host_mesh
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as T
 from repro.optim import make_optimizer
 from repro.train import checkpoint as ckpt
@@ -29,7 +29,7 @@ class TestTrainingConverges:
         cfg = tiny()
         opt = make_optimizer("adam", lr=3e-3)
         state = trainer.init_state(cfg, opt, jax.random.PRNGKey(0))
-        plan = par.make_plan("dp", make_host_mesh())
+        plan = par.make_plan("dp", make_mesh())
         step = jax.jit(trainer.make_train_step(cfg, opt, plan))
         data = SyntheticLM(cfg.vocab_size, 64, noise=0.05)
         losses = []
@@ -67,6 +67,22 @@ class TestCheckpoint:
         with pytest.raises((ValueError, KeyError)):
             ckpt.restore(path, bad)
 
+    def test_launcher_resume_saves_global_step(self, tmp_path):
+        """launch.train saves the step count since the first run, not the
+        steps of the last one, so numbering carries across resumes."""
+        from repro.launch.train import train
+        opt = make_optimizer("sgd", lr=0.1)
+        first, second = str(tmp_path / "a.npz"), str(tmp_path / "b.npz")
+        kw = dict(batch=2, seq=16, log=lambda s: None)
+        state, _ = train(tiny(), opt, steps=2, checkpoint=first, **kw)
+        logs = []
+        train(tiny(), opt, steps=3, resume=first, checkpoint=second,
+              **{**kw, "log": logs.append})
+        assert ckpt.restore(first, state)[1] == 2
+        assert ckpt.restore(second, state)[1] == 5
+        assert logs[0] == f"resumed from {first} at step 2"
+        assert logs[1].startswith("step 3: loss=")
+
 
 @pytest.mark.slow
 class TestPaperMode:
@@ -77,14 +93,14 @@ class TestPaperMode:
             from repro.configs.base import ModelConfig
             from repro.core import parallelism as par
             from repro.data.pipeline import SyntheticLM
-            from repro.launch.mesh import make_host_mesh
+            from repro.launch.mesh import make_mesh
             from repro.optim import make_optimizer
             from repro.train import trainer
             cfg = ModelConfig(name='t', family='dense', num_layers=1,
                               d_model=32, num_heads=2, num_kv_heads=2,
                               head_dim=16, d_ff=64, vocab_size=32,
                               loss_chunk=32, attn_chunk=32, remat=False)
-            mesh = make_host_mesh((4,), ('data',))
+            mesh = make_mesh((4,), ('data',))
             opt = make_optimizer('sgd', lr=1e-2)
             data = SyntheticLM(cfg.vocab_size, 32, noise=0.05)
             batches = list(data.batches(8, 5))
