@@ -129,12 +129,12 @@ MAX_SLOTS = 8
 
 
 def _fresh_engine(cfg, params, prompts, *, prefix_caching=True, prime=None,
-                  telemetry=True, step_timing=False, packed_prefill=True):
+                  telemetry=True, packed_prefill=True):
     eng = Engine(cfg, params, EngineConfig(
         block_size=16, num_blocks=256, max_blocks_per_seq=8,
         max_slots=MAX_SLOTS, prefill_chunk=32, prefills_per_step=4,
         prefix_caching=prefix_caching, telemetry=telemetry,
-        step_timing=step_timing, packed_prefill=packed_prefill))
+        packed_prefill=packed_prefill))
     # warmup: compile decode once on a throwaway request (every prefill
     # bucket is already AOT-compiled at engine construction)
     skip = {eng.add_request(prompts[0][:4], 2)}
@@ -166,15 +166,14 @@ def _run_engine(cfg, params, prompts, max_news, *, prefix_caching=True,
                 collect_latency=False) -> EngineRun:
     """One driver for both measurement modes. Throughput pass
     (`collect_latency=False`): free-running steps, one sync at the end, so
-    the host-ahead pipeline is measured. Latency pass: `step_timing=True`
-    and a block on each step's emitted tokens, so per-step wall time — and
-    the engine's own request-lifecycle timestamps (TTFT, queue wait) — are
-    device-completion times, not async dispatch. Warmup and cache-priming
-    tokens/steps are excluded from every reported number."""
+    the host-ahead pipeline is measured. Latency pass: each step's emitted
+    tokens are read back to the host before the next step, so per-step wall
+    time — and the engine's TTFT, which closes at a request's first
+    ``deliver`` — are delivery times, not async dispatch. Warmup and
+    cache-priming tokens/steps are excluded from every reported number."""
     eng, skip = _fresh_engine(cfg, params, prompts,
                               prefix_caching=prefix_caching, prime=prime,
                               telemetry=telemetry,
-                              step_timing=collect_latency,
                               packed_prefill=packed_prefill)
     warm = dict(eng.stats)
     for p, mn in zip(prompts, max_news):
@@ -185,7 +184,8 @@ def _run_engine(cfg, params, prompts, max_news, *, prefix_caching=True,
         while eng.scheduler.has_work:
             s = time.perf_counter()
             emitted = eng.step()
-            jax.block_until_ready(eng.next_tok)
+            for rid in dict.fromkeys(emitted):
+                eng.output(rid)                    # blocks: values on host
             lat.extend([time.perf_counter() - s] * len(emitted))
     outs = eng.drain()                             # materializes every token
     wall = time.perf_counter() - t0
@@ -324,12 +324,6 @@ def _main_mixed(cfg, params, trace_out=None, seed=0):
              float(np.percentile(ttft_u, q)) * 1e6)
     emit("serving_packed_prefill_ttft_speedup", None,
          f"{np.percentile(ttft_u, 50) / np.percentile(ttft_p, 50):.2f}x")
-    # host/device split of the synced pass (engine-step timeline)
-    host = eng_lat.telemetry.registry.get("engine_step_host_seconds")
-    dev = eng_lat.telemetry.registry.get("engine_step_device_seconds")
-    if dev.sum + host.sum > 0:
-        emit("serving_engine_step_host_fraction", None,
-             f"{host.sum / (host.sum + dev.sum):.3f}")
     emit("serving_speedup_vs_legacy_batched", None,
          f"{tps_engine / tps_legacy:.2f}x")
     emit("serving_speedup_vs_legacy_loop", None, f"{tps_engine / tps_loop:.2f}x")
@@ -437,8 +431,6 @@ def _run_open_loop(cfg, params, arrivals, ecfg, *, synced=False):
     come, step the engine, repeat. Arrivals never wait for completions —
     under overload the waiting queue grows and the scheduler must cope.
     Returns (tokens, wall, steps, engine, skip)."""
-    if synced:
-        ecfg = dataclasses.replace(ecfg, step_timing=True)
     eng = Engine(cfg, params, ecfg)
     skip = {eng.add_request(arrivals[0].prompt[:4], 2)}   # decode warmup
     eng.drain()
@@ -450,9 +442,10 @@ def _run_open_loop(cfg, params, arrivals, ecfg, *, synced=False):
             eng.add_request(a.prompt, a.max_new, priority=a.priority)
             i += 1
         if eng.scheduler.has_work:
-            eng.step()
-            if synced:
-                jax.block_until_ready(eng.next_tok)
+            emitted = eng.step()
+            if synced:                  # values on the host: TTFT closes
+                for rid in dict.fromkeys(emitted):
+                    eng.output(rid)
             step += 1
         else:
             step = arrivals[i].step                        # idle: fast-forward
@@ -495,8 +488,9 @@ def _main_oversub(trace_out=None, seed=0):
     emit("serving_oversub_resumes", None, str(st["resumes"]))
     emit("serving_oversub_block_appends", None, str(st["block_appends"]))
 
-    # tail latencies from a synced pass of the optimistic engine: per-step
-    # blocking makes every lifecycle timestamp a device-completion time
+    # tail latencies from a synced pass of the optimistic engine: reading
+    # each step's tokens makes TTFT a delivery time; TPOT below still reads
+    # the dispatch events of a loop that blocks every step
     _t, _w, _n, eng_s, skip_s = _run_open_loop(
         cfg, params, arrivals, _ov_ecfg(OversubConfig()), synced=True)
     _emit_lifecycle("oversub", eng_s, skip_s, trace_out)

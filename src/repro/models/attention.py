@@ -80,18 +80,20 @@ def _project_qkv(params, x, positions, cfg, window):
 
 def _attend_full(q, k, v, n_rep, scale, chunk, window):
     """Full-sequence causal(+window) attention, dispatching naive/chunked
-    (chunked needs S % chunk == 0; odd lengths take the naive path)."""
+    (chunked needs S % chunk == 0; odd lengths take the naive path). Runs
+    under the ``attn`` named scope."""
     S = q.shape[1]
-    if S <= chunk or S % chunk != 0:
-        kk = _repeat_kv(k, n_rep)
-        vv = _repeat_kv(v, n_rep)
-        qpos = jnp.arange(S)
-        scores = jnp.einsum("bqhd,bkhd->bhqk", q, kk).astype(jnp.float32) * scale
-        mask = _mask(qpos, qpos, window)
-        scores = jnp.where(mask[None, None], scores, NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        return jnp.einsum("bhqk,bkhd->bqhd", probs, vv)
-    return _chunked_attention(q, k, v, n_rep, scale, chunk, window)
+    with jax.named_scope("attn"):
+        if S <= chunk or S % chunk != 0:
+            kk = _repeat_kv(k, n_rep)
+            vv = _repeat_kv(v, n_rep)
+            qpos = jnp.arange(S)
+            scores = jnp.einsum("bqhd,bkhd->bhqk", q, kk).astype(jnp.float32) * scale
+            mask = _mask(qpos, qpos, window)
+            scores = jnp.where(mask[None, None], scores, NEG_INF)
+            probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+            return jnp.einsum("bhqk,bkhd->bqhd", probs, vv)
+        return _chunked_attention(q, k, v, n_rep, scale, chunk, window)
 
 
 def attention_train(params, x, positions, cfg, *, window=None, impl="chunked"):
@@ -215,26 +217,27 @@ def paged_write(kv, k_new, v_new, block_tables, positions, active, *,
     so the sequence never touches more than ring_pages blocks. An int8 pool
     (with "k_scale"/"v_scale") quantizes on write, scattering the scales at
     the same (block, offset)."""
-    N, bs = kv["k"].shape[0], kv["k"].shape[1]
-    B = positions.shape[0]
-    pages = positions // bs
-    if ring_pages is not None:
-        pages = pages % ring_pages
-    bids = block_tables[jnp.arange(B), pages]
-    bids = jnp.where(active, bids, N)       # OOB => mode="drop"
-    offs = positions % bs
-    if "k_scale" in kv:
-        qk, sk, qv, sv = _quantize_pair(k_new, v_new)
+    with jax.named_scope("kv_write"):
+        N, bs = kv["k"].shape[0], kv["k"].shape[1]
+        B = positions.shape[0]
+        pages = positions // bs
+        if ring_pages is not None:
+            pages = pages % ring_pages
+        bids = block_tables[jnp.arange(B), pages]
+        bids = jnp.where(active, bids, N)       # OOB => mode="drop"
+        offs = positions % bs
+        if "k_scale" in kv:
+            qk, sk, qv, sv = _quantize_pair(k_new, v_new)
+            return {
+                "k": kv["k"].at[bids, offs].set(qk, mode="drop"),
+                "v": kv["v"].at[bids, offs].set(qv, mode="drop"),
+                "k_scale": kv["k_scale"].at[bids, offs].set(sk, mode="drop"),
+                "v_scale": kv["v_scale"].at[bids, offs].set(sv, mode="drop"),
+            }
         return {
-            "k": kv["k"].at[bids, offs].set(qk, mode="drop"),
-            "v": kv["v"].at[bids, offs].set(qv, mode="drop"),
-            "k_scale": kv["k_scale"].at[bids, offs].set(sk, mode="drop"),
-            "v_scale": kv["v_scale"].at[bids, offs].set(sv, mode="drop"),
+            "k": kv["k"].at[bids, offs].set(k_new, mode="drop"),
+            "v": kv["v"].at[bids, offs].set(v_new, mode="drop"),
         }
-    return {
-        "k": kv["k"].at[bids, offs].set(k_new, mode="drop"),
-        "v": kv["v"].at[bids, offs].set(v_new, mode="drop"),
-    }
 
 
 def attention_decode_paged(params, x, kv, block_tables, positions, attn_lens,
@@ -258,16 +261,18 @@ def attention_decode_paged(params, x, kv, block_tables, positions, attn_lens,
     q, k_new, v_new = _project_qkv(params, x, pos_b1, cfg, window)
     kv = paged_write(kv, k_new[:, 0], v_new[:, 0], block_tables, positions,
                      attn_lens > 0, ring_pages=ring_pages)
-    scales = dict(k_scale=kv.get("k_scale"), v_scale=kv.get("v_scale"))
-    if (impl or platform.paged_attn_impl()) == "kernel":
-        out = paged_attention(q[:, 0], kv["k"], kv["v"], block_tables,
-                              attn_lens, window=window, positions=positions,
-                              ring_pages=ring_pages, **scales)
-    else:
-        out = paged_attention_ref(q[:, 0], kv["k"], kv["v"], block_tables,
+    with jax.named_scope("attn"):
+        scales = dict(k_scale=kv.get("k_scale"), v_scale=kv.get("v_scale"))
+        if (impl or platform.paged_attn_impl()) == "kernel":
+            out = paged_attention(q[:, 0], kv["k"], kv["v"], block_tables,
                                   attn_lens, window=window,
                                   positions=positions, ring_pages=ring_pages,
                                   **scales)
+        else:
+            out = paged_attention_ref(q[:, 0], kv["k"], kv["v"], block_tables,
+                                      attn_lens, window=window,
+                                      positions=positions,
+                                      ring_pages=ring_pages, **scales)
     out = out.reshape(B, 1, h * hd)
     return jnp.einsum("bsk,kd->bsd", out, params["wo"]), kv
 
@@ -282,25 +287,26 @@ def paged_write_multi(kv, k_new, v_new, block_tables, positions, valid, *,
     dropped (OOB block id) so pool contents stay canonical. ring_pages:
     sliding-window layers write page (pos // bs) % ring_pages. Int8 pools
     quantize on write as in :func:`paged_write`."""
-    N, bs = kv["k"].shape[0], kv["k"].shape[1]
-    pages = positions // bs
-    if ring_pages is not None:
-        pages = pages % ring_pages
-    bids = jnp.take_along_axis(block_tables, pages, axis=1)       # (B, K)
-    bids = jnp.where(valid, bids, N)        # OOB => mode="drop"
-    offs = positions % bs
-    if "k_scale" in kv:
-        qk, sk, qv, sv = _quantize_pair(k_new, v_new)
+    with jax.named_scope("kv_write"):
+        N, bs = kv["k"].shape[0], kv["k"].shape[1]
+        pages = positions // bs
+        if ring_pages is not None:
+            pages = pages % ring_pages
+        bids = jnp.take_along_axis(block_tables, pages, axis=1)       # (B, K)
+        bids = jnp.where(valid, bids, N)        # OOB => mode="drop"
+        offs = positions % bs
+        if "k_scale" in kv:
+            qk, sk, qv, sv = _quantize_pair(k_new, v_new)
+            return {
+                "k": kv["k"].at[bids, offs].set(qk, mode="drop"),
+                "v": kv["v"].at[bids, offs].set(qv, mode="drop"),
+                "k_scale": kv["k_scale"].at[bids, offs].set(sk, mode="drop"),
+                "v_scale": kv["v_scale"].at[bids, offs].set(sv, mode="drop"),
+            }
         return {
-            "k": kv["k"].at[bids, offs].set(qk, mode="drop"),
-            "v": kv["v"].at[bids, offs].set(qv, mode="drop"),
-            "k_scale": kv["k_scale"].at[bids, offs].set(sk, mode="drop"),
-            "v_scale": kv["v_scale"].at[bids, offs].set(sv, mode="drop"),
+            "k": kv["k"].at[bids, offs].set(k_new, mode="drop"),
+            "v": kv["v"].at[bids, offs].set(v_new, mode="drop"),
         }
-    return {
-        "k": kv["k"].at[bids, offs].set(k_new, mode="drop"),
-        "v": kv["v"].at[bids, offs].set(v_new, mode="drop"),
-    }
 
 
 def attention_verify_paged(params, x, kv, block_tables, base, qlims, cfg, *,
@@ -329,15 +335,16 @@ def attention_verify_paged(params, x, kv, block_tables, base, qlims, cfg, *,
                            ring_pages=ring_pages)
     attn_lens = jnp.where(qlims > 0, base + K, 0)
     newest = attn_lens - 1
-    scales = dict(k_scale=kv.get("k_scale"), v_scale=kv.get("v_scale"))
-    if (impl or platform.paged_attn_impl()) == "kernel":
-        out = paged_attention_verify(
-            q, kv["k"], kv["v"], block_tables, attn_lens, window=window,
-            positions=newest, ring_pages=ring_pages, **scales)
-    else:
-        out = paged_attention_verify_ref(
-            q, kv["k"], kv["v"], block_tables, attn_lens, window=window,
-            positions=newest, ring_pages=ring_pages, **scales)
+    with jax.named_scope("attn"):
+        scales = dict(k_scale=kv.get("k_scale"), v_scale=kv.get("v_scale"))
+        if (impl or platform.paged_attn_impl()) == "kernel":
+            out = paged_attention_verify(
+                q, kv["k"], kv["v"], block_tables, attn_lens, window=window,
+                positions=newest, ring_pages=ring_pages, **scales)
+        else:
+            out = paged_attention_verify_ref(
+                q, kv["k"], kv["v"], block_tables, attn_lens, window=window,
+                positions=newest, ring_pages=ring_pages, **scales)
     out = out.reshape(B, K, h * hd)
     return jnp.einsum("bsk,kd->bsd", out, params["wo"]), kv
 
@@ -362,43 +369,47 @@ def attention_prefill_paged(params, x, kv, table_rows, starts, valids, cfg):
     q, k, v = _project_qkv(params, x, positions, cfg, None)
 
     N, bs = kv["k"].shape[0], kv["k"].shape[1]
-    valid = jnp.arange(C)[None, :] < valids[:, None]              # (G, C)
-    bids = jnp.where(
-        valid, jnp.take_along_axis(table_rows, pos // bs, axis=1), N)
-    offs = pos % bs
-    if "k_scale" in kv:
-        qk, sk, qv, sv = _quantize_pair(k, v)
-        kv = {
-            "k": kv["k"].at[bids, offs].set(qk, mode="drop"),
-            "v": kv["v"].at[bids, offs].set(qv, mode="drop"),
-            "k_scale": kv["k_scale"].at[bids, offs].set(sk, mode="drop"),
-            "v_scale": kv["v_scale"].at[bids, offs].set(sv, mode="drop"),
-        }
-    else:
-        kv = {
-            "k": kv["k"].at[bids, offs].set(k, mode="drop"),
-            "v": kv["v"].at[bids, offs].set(v, mode="drop"),
-        }
+    with jax.named_scope("kv_write"):
+        valid = jnp.arange(C)[None, :] < valids[:, None]              # (G, C)
+        bids = jnp.where(
+            valid, jnp.take_along_axis(table_rows, pos // bs, axis=1), N)
+        offs = pos % bs
+        if "k_scale" in kv:
+            qk, sk, qv, sv = _quantize_pair(k, v)
+            kv = {
+                "k": kv["k"].at[bids, offs].set(qk, mode="drop"),
+                "v": kv["v"].at[bids, offs].set(qv, mode="drop"),
+                "k_scale": kv["k_scale"].at[bids, offs].set(sk, mode="drop"),
+                "v_scale": kv["v_scale"].at[bids, offs].set(sv, mode="drop"),
+            }
+        else:
+            kv = {
+                "k": kv["k"].at[bids, offs].set(k, mode="drop"),
+                "v": kv["v"].at[bids, offs].set(v, mode="drop"),
+            }
 
     # the gather-back below reads the (possibly quantized) pool contents, so
     # every query attends the same values the decode kernel will later see
     from repro.kernels.paged_attention.ref import _gather_pool
-    P = table_rows.shape[1]
-    n_rep = h // hkv
-    if "k_scale" in kv:
-        kk = _repeat_kv(
-            _gather_pool(kv["k"], kv["k_scale"], table_rows, P * bs), n_rep)
-        vv = _repeat_kv(
-            _gather_pool(kv["v"], kv["v_scale"], table_rows, P * bs), n_rep)
-    else:
-        kk = _repeat_kv(kv["k"][table_rows].reshape(G, P * bs, hkv, hd), n_rep)
-        vv = _repeat_kv(kv["v"][table_rows].reshape(G, P * bs, hkv, hd), n_rep)
-    scale = 1.0 / np.sqrt(hd)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, kk).astype(jnp.float32) * scale
-    mask = jnp.arange(P * bs)[None, None, :] <= pos[:, :, None]   # (G, C, P*bs)
-    s = jnp.where(mask[:, None], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1).astype(x.dtype)
-    out = jnp.einsum("bhqk,bkhd->bqhd", p, vv).reshape(G, C, h * hd)
+    with jax.named_scope("attn"):
+        P = table_rows.shape[1]
+        n_rep = h // hkv
+        if "k_scale" in kv:
+            kk = _repeat_kv(_gather_pool(kv["k"], kv["k_scale"], table_rows,
+                                         P * bs), n_rep)
+            vv = _repeat_kv(_gather_pool(kv["v"], kv["v_scale"], table_rows,
+                                         P * bs), n_rep)
+        else:
+            kk = _repeat_kv(kv["k"][table_rows].reshape(G, P * bs, hkv, hd),
+                            n_rep)
+            vv = _repeat_kv(kv["v"][table_rows].reshape(G, P * bs, hkv, hd),
+                            n_rep)
+        scale = 1.0 / np.sqrt(hd)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, kk).astype(jnp.float32) * scale
+        mask = jnp.arange(P * bs)[None, None, :] <= pos[:, :, None]  # G,C,P*bs
+        s = jnp.where(mask[:, None], s, NEG_INF)
+        p = jax.nn.softmax(s, axis=-1).astype(x.dtype)
+        out = jnp.einsum("bhqk,bkhd->bqhd", p, vv).reshape(G, C, h * hd)
     return jnp.einsum("bsk,kd->bsd", out, params["wo"]), kv
 
 
@@ -438,13 +449,14 @@ def attention_prefill_ring(params, x, kv, table_rows, starts, valids, cfg,
 
     # 1) gather each segment's ring as of starts-1 (before this chunk's
     # writes)
-    ring_rows = table_rows[:, :R]                                 # (G, R)
-    if quant:
-        old_k = _gather_pool(kv["k"], kv["k_scale"], ring_rows, R * bs)
-        old_v = _gather_pool(kv["v"], kv["v_scale"], ring_rows, R * bs)
-    else:
-        old_k = kv["k"][ring_rows].reshape(G, R * bs, hkv, hd)
-        old_v = kv["v"][ring_rows].reshape(G, R * bs, hkv, hd)
+    with jax.named_scope("attn"):
+        ring_rows = table_rows[:, :R]                                 # (G, R)
+        if quant:
+            old_k = _gather_pool(kv["k"], kv["k_scale"], ring_rows, R * bs)
+            old_v = _gather_pool(kv["v"], kv["v_scale"], ring_rows, R * bs)
+        else:
+            old_k = kv["k"][ring_rows].reshape(G, R * bs, hkv, hd)
+            old_v = kv["v"][ring_rows].reshape(G, R * bs, hkv, hd)
     old_pos = ring_key_positions(starts - 1, R, bs)               # (G, R*bs)
     # entries the pre-chunk ring never held: pages < 0 entirely, and the
     # current page's offsets past (start-1) % bs (previous-lap leftovers,
@@ -457,39 +469,41 @@ def attention_prefill_ring(params, x, kv, table_rows, starts, valids, cfg,
     # leaves duplicate-index order undefined, so only each (slot, offset)'s
     # newest lap may write. Skipped positions are > R*bs > window older
     # than the chunk's last token — nothing downstream can attend them.
-    last_valid = (starts + valids - 1)[:, None]                   # (G, 1)
-    write = ((jnp.arange(C)[None, :] < valids[:, None])
-             & (pos > last_valid - R * bs))
-    bids = jnp.where(
-        write, jnp.take_along_axis(table_rows, (pos // bs) % R, axis=1), N)
-    offs = pos % bs
-    if quant:
-        kv = {
-            "k": kv["k"].at[bids, offs].set(qk, mode="drop"),
-            "v": kv["v"].at[bids, offs].set(qv, mode="drop"),
-            "k_scale": kv["k_scale"].at[bids, offs].set(sk, mode="drop"),
-            "v_scale": kv["v_scale"].at[bids, offs].set(sv, mode="drop"),
-        }
-    else:
-        kv = {
-            "k": kv["k"].at[bids, offs].set(k, mode="drop"),
-            "v": kv["v"].at[bids, offs].set(v, mode="drop"),
-        }
+    with jax.named_scope("kv_write"):
+        last_valid = (starts + valids - 1)[:, None]                   # (G, 1)
+        write = ((jnp.arange(C)[None, :] < valids[:, None])
+                 & (pos > last_valid - R * bs))
+        bids = jnp.where(
+            write, jnp.take_along_axis(table_rows, (pos // bs) % R, axis=1), N)
+        offs = pos % bs
+        if quant:
+            kv = {
+                "k": kv["k"].at[bids, offs].set(qk, mode="drop"),
+                "v": kv["v"].at[bids, offs].set(qv, mode="drop"),
+                "k_scale": kv["k_scale"].at[bids, offs].set(sk, mode="drop"),
+                "v_scale": kv["v_scale"].at[bids, offs].set(sv, mode="drop"),
+            }
+        else:
+            kv = {
+                "k": kv["k"].at[bids, offs].set(k, mode="drop"),
+                "v": kv["v"].at[bids, offs].set(v, mode="drop"),
+            }
 
     # 3) attend: keys = each segment's pre-chunk ring ∪ its own chunk
-    n_rep = h // hkv
-    kk = _repeat_kv(jnp.concatenate([old_k, k], axis=1), n_rep)
-    vv = _repeat_kv(jnp.concatenate([old_v, v], axis=1), n_rep)
-    kpos = jnp.concatenate([old_pos, pos], axis=1)                # (G, R*bs+C)
-    kok = jnp.concatenate([old_ok, jnp.ones((G, C), bool)], axis=1)
-    scale = 1.0 / np.sqrt(hd)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, kk).astype(jnp.float32) * scale
-    mask = (kok[:, None, :]
-            & (kpos[:, None, :] <= pos[:, :, None])
-            & (kpos[:, None, :] > pos[:, :, None] - window))      # (G, C, K)
-    s = jnp.where(mask[:, None], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1).astype(x.dtype)
-    out = jnp.einsum("bhqk,bkhd->bqhd", p, vv).reshape(G, C, h * hd)
+    with jax.named_scope("attn"):
+        n_rep = h // hkv
+        kk = _repeat_kv(jnp.concatenate([old_k, k], axis=1), n_rep)
+        vv = _repeat_kv(jnp.concatenate([old_v, v], axis=1), n_rep)
+        kpos = jnp.concatenate([old_pos, pos], axis=1)          # (G, R*bs+C)
+        kok = jnp.concatenate([old_ok, jnp.ones((G, C), bool)], axis=1)
+        scale = 1.0 / np.sqrt(hd)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, kk).astype(jnp.float32) * scale
+        mask = (kok[:, None, :]
+                & (kpos[:, None, :] <= pos[:, :, None])
+                & (kpos[:, None, :] > pos[:, :, None] - window))  # (G, C, K)
+        s = jnp.where(mask[:, None], s, NEG_INF)
+        p = jax.nn.softmax(s, axis=-1).astype(x.dtype)
+        out = jnp.einsum("bhqk,bkhd->bqhd", p, vv).reshape(G, C, h * hd)
     return jnp.einsum("bsk,kd->bsd", out, params["wo"]), kv
 
 

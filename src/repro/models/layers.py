@@ -50,10 +50,11 @@ def init_swiglu(key, d, d_ff, dtype):
 
 
 def swiglu(params, x):
-    g = jnp.einsum("...d,df->...f", x, params["w_gate"])
-    h = jnp.einsum("...d,df->...f", x, params["w_in"])
-    act = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * h
-    return jnp.einsum("...f,fd->...d", act, params["w_out"])
+    with jax.named_scope("mlp"):
+        g = jnp.einsum("...d,df->...f", x, params["w_gate"])
+        h = jnp.einsum("...d,df->...f", x, params["w_in"])
+        act = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * h
+        return jnp.einsum("...f,fd->...d", act, params["w_out"])
 
 
 # ----------------------------------------------------------------------- rope

@@ -33,6 +33,7 @@ def init_moe(key, cfg):
     }
 
 
+@jax.named_scope("mlp")
 def moe_apply(params, x, cfg, constrain=None):
     """x: (B, S, D) -> (B, S, D). constrain: optional fn(tensor, names) that
     applies sharding constraints on the dispatch buffers."""
@@ -95,6 +96,7 @@ def moe_apply(params, x, cfg, constrain=None):
     return out[:T].reshape(B, S, D)
 
 
+@jax.named_scope("mlp")
 def moe_apply_ep(params, x, cfg, plan):
     """Expert-parallel fast path (survey §5.2 made communication-optimal).
 

@@ -180,7 +180,8 @@ def forward(cfg: ModelConfig, params, inputs):
         return sb_fn(x, sb_params)
 
     x, auxs = jax.lax.scan(scan_body, x, params["blocks"])
-    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+    with jax.named_scope("head"):
+        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     return x, jnp.sum(auxs)
 
 
@@ -217,7 +218,8 @@ def loss_fn(cfg: ModelConfig, params, batch):
         gold = jnp.sum(lg * onehot, axis=-1)
         return tot + jnp.sum(lse - gold), None
 
-    total, _ = jax.lax.scan(step, jnp.float32(0.0), (hc, lc))
+    with jax.named_scope("head"):
+        total, _ = jax.lax.scan(step, jnp.float32(0.0), (hc, lc))
     ce = total / (B * Sq)
     return ce + 0.01 * aux
 
@@ -332,8 +334,9 @@ def prefill_step(cfg: ModelConfig, params, state, inputs):
         return x, new_cache
 
     x, new_caches = jax.lax.scan(scan_body, x, (params["blocks"], state))
-    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    lg = logits(cfg, params, x[:, -1:])[:, 0]
+    with jax.named_scope("head"):
+        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        lg = logits(cfg, params, x[:, -1:])[:, 0]
     return lg, new_caches
 
 
@@ -424,8 +427,9 @@ def paged_decode_step(cfg: ModelConfig, params, pool, inputs, block_tables,
         return x, new_pool
 
     x, new_pools = jax.lax.scan(scan_body, x, (params["blocks"], pool))
-    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    lg = logits(cfg, params, x)[:, 0]
+    with jax.named_scope("head"):
+        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        lg = logits(cfg, params, x)[:, 0]
     return lg, new_pools
 
 
@@ -488,8 +492,9 @@ def paged_verify_step(cfg: ModelConfig, params, pool, tokens, block_tables,
         return x, new_pool
 
     x, new_pools = jax.lax.scan(scan_body, x, (params["blocks"], pool))
-    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    lg = logits(cfg, params, x)                                   # (B, K, V)
+    with jax.named_scope("head"):
+        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        lg = logits(cfg, params, x)                               # (B, K, V)
     return lg, new_pools
 
 
@@ -566,10 +571,11 @@ def paged_prefill_packed(cfg: ModelConfig, params, pool, tokens, tables,
         return x, new_pool
 
     x, new_pools = jax.lax.scan(scan_body, x, (params["blocks"], pool))
-    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    idx = jnp.maximum(valids - 1, 0)                              # (G,)
-    last = jnp.take_along_axis(x, idx[:, None, None], axis=1)     # (G, 1, D)
-    lg = logits(cfg, params, last)[:, 0]
+    with jax.named_scope("head"):
+        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        idx = jnp.maximum(valids - 1, 0)                          # (G,)
+        last = jnp.take_along_axis(x, idx[:, None, None], axis=1)  # (G,1,D)
+        lg = logits(cfg, params, last)[:, 0]
     return lg, new_pools
 
 
@@ -609,6 +615,7 @@ def decode_step(cfg: ModelConfig, params, state, inputs, index):
         return x, new_cache
 
     x, new_caches = jax.lax.scan(scan_body, x, (params["blocks"], state))
-    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    lg = logits(cfg, params, x)[:, 0]
+    with jax.named_scope("head"):
+        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        lg = logits(cfg, params, x)[:, 0]
     return lg, new_caches

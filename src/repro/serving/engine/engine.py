@@ -48,15 +48,20 @@ activation constraints apply.
 Telemetry (``EngineConfig.telemetry``, on by default; see
 ``serving.telemetry`` and the README's Telemetry section): every request's
 lifecycle (arrive/admit/prefix_hit/prefill_chunk/first_token/decode_token/
-finish) is traced with monotonic timestamps, all engine and pool counters
-live in one metrics registry (``Engine.stats`` remains as a back-compat
-read-only view), the jitted step fns are wrapped to count unique trace keys
-(distinct compiled variants), and prefill/decode run under
-``jax.profiler.TraceAnnotation`` spans. ``EngineConfig.step_timing``
-additionally blocks on device results inside ``step()`` to split host
-scheduling time from device time per step — only the timing path blocks, so
-throughput runs keep the async host-ahead pipeline. Telemetry never changes
-emitted tokens: greedy outputs are bit-identical with it on or off.
+deliver/finish) is traced with monotonic timestamps and the engine step
+number, all engine and pool counters live in one metrics registry
+(``Engine.stats`` remains as a back-compat read-only view), and the jitted
+step fns are wrapped to count unique trace keys (distinct compiled
+variants). The host loop runs under ``jax.profiler.TraceAnnotation``
+spans that cover every moment of a step: ``engine/step`` (``step=n``)
+holds ``engine/schedule`` (admission, slot-state updates, batch building),
+one span per device dispatch (``engine/prefill``, ``engine/decode``,
+``engine/verify``, ``engine/copy_block``, ``engine/reset_slot``) and
+``engine/emit`` (token records, finishes, end-of-step metrics);
+``engine/sync`` marks each transfer of step-vector values to the host and
+``engine/add_request`` each submission. Nothing blocks for telemetry's
+sake, and it never changes emitted tokens: greedy outputs are bit-identical
+with it on or off.
 """
 from __future__ import annotations
 
@@ -97,7 +102,6 @@ class EngineConfig:
                                         #   None = the platform's choice
                                         #   (repro.kernels.platform)
     telemetry: bool = True              # lifecycle tracing + metrics registry
-    step_timing: bool = False           # block per device call to time steps
     prefill_buckets: tuple = ()         # chunk-length buckets; () = one
                                         #   bucket of prefill_chunk tokens
     packed_prefill: bool = True         # pack chunks into one prefill call
@@ -146,7 +150,8 @@ def _build_step_fns(cfg, e: EngineConfig, plan):
         logits, pool = T.paged_decode_step(
             cfg, params, pool, {"token": tokens}, tables, positions,
             attn_lens, impl=e.attn_impl, draft=draft)
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("head"):
+            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return greedy, logits, seq_lens + active, pool
 
     @functools.partial(jax.jit, donate_argnums=(1,))
@@ -158,7 +163,8 @@ def _build_step_fns(cfg, e: EngineConfig, plan):
         logits, pool = T.paged_prefill_packed(
             cfg, params, pool, tokens, tables, starts, valids, slots,
             draft=draft)
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("head"):
+            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return greedy, logits, pool
 
     verify_fn = None
@@ -205,9 +211,8 @@ def _step_fn_key(e: EngineConfig) -> EngineConfig:
     key: it changes the pool pytree structure the steps are traced with."""
     spec = SpecConfig(k=e.spec.k) if e.spec is not None else None
     return dataclasses.replace(e, prefix_caching=True, prefills_per_step=1,
-                               telemetry=True, step_timing=False,
-                               prefill_buckets=(), packed_prefill=True,
-                               oversub=None, spec=spec)
+                               telemetry=True, prefill_buckets=(),
+                               packed_prefill=True, oversub=None, spec=spec)
 
 
 @functools.lru_cache(maxsize=None)
@@ -256,8 +261,7 @@ class Engine:
         # telemetry: one registry + tracer + recompile tracker per engine.
         # The pool shares the registry so `pool_*` metrics export alongside
         # `engine_*`; everything is host-side and disabled-path cheap.
-        self.telemetry = TM.Telemetry(enabled=e.telemetry,
-                                      step_timing=e.step_timing)
+        self.telemetry = TM.Telemetry(enabled=e.telemetry)
         reg = self.telemetry.registry
         self._m_decode_steps = reg.counter(
             "engine_decode_steps_total", "batched decode steps dispatched")
@@ -306,7 +310,8 @@ class Engine:
         self._h_queue_wait = reg.histogram(
             "engine_request_queue_wait_seconds", "arrive -> admit wait")
         self._h_ttft = reg.histogram(
-            "engine_request_ttft_seconds", "arrive -> first token")
+            "engine_request_ttft_seconds",
+            "arrive -> first token's value on the host (first deliver)")
         self._h_e2e = reg.histogram(
             "engine_request_e2e_seconds", "arrive -> finish")
 
@@ -374,6 +379,7 @@ class Engine:
 
         self._next_rid = 0
         self.requests: dict = {}        # rid -> Request (all ever submitted)
+        self.step_count = 0             # engine steps begun
 
         if plan is None:
             (self._decode, self._prefill, self._copy_block, self._reset_slot,
@@ -393,7 +399,6 @@ class Engine:
             self._reset_slot = wrap("reset_slot", self._reset_slot)
             if self._verify is not None:
                 self._verify = wrap("verify", self._verify)
-        self._step_device_s = 0.0
         self._warmup_prefill()
         self._warmup_verify()
 
@@ -467,55 +472,46 @@ class Engine:
         per-sequence block table and the whole pool, so infeasible requests
         fail here with the offending numbers instead of deep inside the
         scheduler."""
-        prompt = np.asarray(prompt, np.int32).reshape(-1)
-        if prompt.shape[0] < 1:
-            raise ValueError("prompt must contain at least one token")
-        if max_new < 1:
-            raise ValueError("max_new must be >= 1")
-        e = self.ecfg
-        total = prompt.shape[0] + max_new
-        need = self.blocks_needed(total)
-        if need > e.max_blocks_per_seq:
-            raise ValueError(
-                f"request infeasible: prompt_len {prompt.shape[0]} + max_new "
-                f"{max_new} = {total} tokens needs {need} blocks > "
-                f"max_blocks_per_seq {e.max_blocks_per_seq} "
-                f"(= {e.max_blocks_per_seq * e.block_size} tokens at "
-                f"block_size {e.block_size})")
-        if need > e.num_blocks:
-            raise ValueError(
-                f"request infeasible: prompt_len {prompt.shape[0]} + max_new "
-                f"{max_new} = {total} tokens needs {need} blocks > pool "
-                f"budget num_blocks {e.num_blocks}")
-        if temperature > 0.0 and key is None:
-            key = jax.random.PRNGKey(self._next_rid)
-        rid = self._next_rid
-        self._next_rid += 1
-        req = Request(
-            rid=rid, prompt=prompt, max_new=max_new, temperature=temperature,
-            key=key, stop_token=stop_token, priority=priority,
-            arrive_t=self.telemetry.clock())
-        self.requests[rid] = req
-        self.scheduler.submit(req)
-        self.telemetry.record(rid, "arrive", prompt_len=int(prompt.shape[0]),
-                              max_new=int(max_new))
-        return rid
+        with self.telemetry.span("engine/add_request"):
+            prompt = np.asarray(prompt, np.int32).reshape(-1)
+            if prompt.shape[0] < 1:
+                raise ValueError("prompt must contain at least one token")
+            if max_new < 1:
+                raise ValueError("max_new must be >= 1")
+            e = self.ecfg
+            total = prompt.shape[0] + max_new
+            need = self.blocks_needed(total)
+            if need > e.max_blocks_per_seq:
+                raise ValueError(
+                    f"request infeasible: prompt_len {prompt.shape[0]} + "
+                    f"max_new {max_new} = {total} tokens needs {need} blocks > "
+                    f"max_blocks_per_seq {e.max_blocks_per_seq} "
+                    f"(= {e.max_blocks_per_seq * e.block_size} tokens at "
+                    f"block_size {e.block_size})")
+            if need > e.num_blocks:
+                raise ValueError(
+                    f"request infeasible: prompt_len {prompt.shape[0]} + "
+                    f"max_new {max_new} = {total} tokens needs {need} blocks > "
+                    f"pool budget num_blocks {e.num_blocks}")
+            if temperature > 0.0 and key is None:
+                key = jax.random.PRNGKey(self._next_rid)
+            rid = self._next_rid
+            self._next_rid += 1
+            req = Request(
+                rid=rid, prompt=prompt, max_new=max_new,
+                temperature=temperature, key=key, stop_token=stop_token,
+                priority=priority, arrive_t=self.telemetry.clock())
+            self.requests[rid] = req
+            self.scheduler.submit(req)
+            self.telemetry.record(rid, "arrive",
+                                  prompt_len=int(prompt.shape[0]),
+                                  max_new=int(max_new))
+            return rid
 
     def _device_call(self, span: str, fn, *args):
-        """Dispatch one jitted step under a labeled profiler span. In the
-        timing path (`step_timing`) only, block on the results so the
-        measured interval is device completion rather than async dispatch,
-        and accumulate it into the current step's device time."""
-        tel = self.telemetry
-        if not tel.enabled:
+        """Dispatch one jitted step under a labeled profiler span."""
+        with self.telemetry.span(span):
             return fn(*args)
-        with tel.span(span):
-            if not tel.step_timing:
-                return fn(*args)
-            t0 = tel.clock()
-            out = jax.block_until_ready(fn(*args))
-            self._step_device_s += tel.clock() - t0
-            return out
 
     def step(self) -> list:
         """One engine iteration: admit -> prefill chunk(s) -> batched decode.
@@ -523,132 +519,98 @@ class Engine:
         (policy-gated) admit + prefill -> per-sequence block growth (with
         victim preemption on append failure) -> batched decode. Returns the
         rids that emitted a token this step (token values are materialized
-        lazily — read them via `drain()` / `output()`)."""
+        lazily — read them via `drain()` / `output()`).
+
+        The step is the profiler span ``engine/step`` (``step=n``, n the
+        value of ``step_count`` before the call). Every host moment of it
+        lies in exactly one child span, in order: ``engine/schedule``, the
+        device dispatches, ``engine/emit``."""
+        tel = self.telemetry
+        n = self.step_count
+        self.step_count += 1
+        tel.tracer.step = n
+        with tel.span("engine/step", step=n):
+            return self._step()
+
+    def _step(self) -> list:
         e = self.ecfg
         tel = self.telemetry
+        pol = self._policy
+        t_wall = tel.clock() if pol is not None else 0.0
         emitted = []
-        self._step_device_s = 0.0
-        t_step = tel.clock() if tel.step_timing else 0.0
-        t_wall = tel.clock() if self._policy is not None else 0.0
-        n_prefills = 0
         sync_memo = {}                  # one host transfer per step vector
 
-        pol = self._policy
-        if pol is not None and pol.cfg.priority_preemption:
-            self._priority_preempt()
-        allow_prefill = True
-        if pol is not None:
-            head_wait = None
-            if self.scheduler.waiting:
-                head = self.scheduler.waiting[0]
-                if head.arrive_t is not None:
-                    head_wait = pol.clock() - head.arrive_t
-            decoding = sum(1 for r in self.scheduler.running.values()
-                           if r.state == DECODING)
-            allow_prefill = pol.allow_prefill(
-                head_wait_s=head_wait, decoding=decoding,
-                pool_util=self.block_pool.utilization)
-            if not allow_prefill:
-                self._m_prefill_deferrals.inc()
+        with tel.span("engine/schedule"):
+            allow_prefill = self._allow_prefill()
+            admitted = self.scheduler.admit() if allow_prefill else []
+        for req in admitted if self._has_recurrent else []:
+            # the slot's recurrent slab rows still hold the previous
+            # occupant's final state — zero them for the newcomer
+            self.pool_state = self._device_call(
+                "engine/reset_slot", self._reset_slot,
+                self.pool_state, jnp.int32(req.slot))
+        with tel.span("engine/schedule"):
+            copies = [self._seat(req) for req in admitted]
+            batches = self.scheduler.next_prefills() if allow_prefill else []
+        for src, dst in filter(None, copies):
+            # whole prefill cached: copy the last matched block into the
+            # private block at its table position, then re-prefill only
+            # the final token there (yields the first-token logits)
+            self.pool_state = self._device_call(
+                "engine/copy_block", self._copy_block,
+                self.pool_state, jnp.int32(src), jnp.int32(dst))
+            self._m_cow.inc()
 
-        admitted = self.scheduler.admit() if allow_prefill else []
-        for req in admitted:
-            row = self.block_pool.table(req.rid)
-            padded = np.zeros((e.max_blocks_per_seq,), np.int32)
-            padded[:len(row)] = row
-            self.tables = self.tables.at[req.slot].set(jnp.asarray(padded))
-            if self._has_recurrent:
-                # the slot's recurrent slab rows still hold the previous
-                # occupant's final state — zero them for the newcomer
-                self.pool_state = self._device_call(
-                    "engine/reset_slot", self._reset_slot,
-                    self.pool_state, jnp.int32(req.slot))
-            self._m_prefix_hits.inc(req.prefilled)
-            resumed = req.preempts > 0
-            if tel.enabled:
-                t_admit = tel.record(req.rid, "resume" if resumed else "admit",
-                                     slot=req.slot)
-                if not resumed:
-                    t_arrive = tel.tracer.first(req.rid, "arrive")
-                    if t_arrive is not None:
-                        self._h_queue_wait.observe(t_admit - t_arrive)
-                if req.prefilled:
-                    tel.record(req.rid, "prefix_hit", tokens=req.prefilled,
-                               blocks=req.shared_blocks
-                               + (1 if req.cow_src is not None else 0))
-            if resumed:
-                self._m_resumes.inc()
-            if req.snapshot is not None and self._snapshot_resume:
-                # pure-recurrent resume: scatter the checkpointed slab rows
-                # back into the (freshly zeroed) slot and skip the re-scan —
-                # prefill only covers the tokens past the snapshot
-                self.pool_state = {
-                    f"l{i}": p.resume_restore(
-                        self.pool_state[f"l{i}"], req.slot, req.snapshot[i])
-                    for i, p in enumerate(self.providers)}
-                req.prefilled = req.snapshot_len
-            req.snapshot = None
-            req.snapshot_len = 0
-            self.seq_lens = self.seq_lens.at[req.slot].set(req.prefilled)
-            if req.cow_src is not None:
-                # whole prefill cached: copy the last matched block into the
-                # private block at its table position, then re-prefill only
-                # the final token there (yields the first-token logits)
-                dst = row[req.prefill_len // e.block_size - 1]
-                self.pool_state = self._device_call(
-                    "engine/copy_block", self._copy_block,
-                    self.pool_state, jnp.int32(req.cow_src), jnp.int32(dst))
-                self._m_cow.inc()
-
-        for batch in (self.scheduler.next_prefills() if allow_prefill
-                      else []):
+        for batch in batches:
             # one segment-masked device call per batch: segment j carries
             # request j's chunk, padded to the (C, G) bucket; missing
             # segments get valid=0 and the out-of-range slot sentinel
-            C, G = batch.chunk_len, batch.num_segments
-            tokens = np.zeros((G, C), np.int32)
-            starts = np.zeros((G,), np.int32)
-            valids = np.zeros((G,), np.int32)
-            slots = np.full((G,), e.max_slots, np.int32)
-            for j, (req, start, valid) in enumerate(batch.segments):
-                tokens[j, :valid] = req.prefill_src[start:start + valid]
-                starts[j], valids[j], slots[j] = start, valid, req.slot
+            with tel.span("engine/schedule"):
+                C, G = batch.chunk_len, batch.num_segments
+                tokens = np.zeros((G, C), np.int32)
+                starts = np.zeros((G,), np.int32)
+                valids = np.zeros((G,), np.int32)
+                slots = np.full((G,), e.max_slots, np.int32)
+                for j, (req, start, valid) in enumerate(batch.segments):
+                    tokens[j, :valid] = req.prefill_src[start:start + valid]
+                    starts[j], valids[j], slots[j] = start, valid, req.slot
+                args = (jnp.asarray(tokens), self.tables, jnp.asarray(starts),
+                        jnp.asarray(valids), jnp.asarray(slots))
             greedy, logits, self.pool_state = self._device_call(
                 "engine/prefill", self._prefill,
-                self.params, self.pool_state, jnp.asarray(tokens),
-                self.tables, jnp.asarray(starts), jnp.asarray(valids),
-                jnp.asarray(slots))
-            self._m_bucket[(C, G)].inc()
-            for j, (req, start, valid) in enumerate(batch.segments):
-                req.prefilled += valid
-                self.scheduler.register_prefilled(req)
-                self.seq_lens = self.seq_lens.at[req.slot].set(req.prefilled)
-                self._m_prefill_chunks.inc()
-                n_prefills += 1
-                tel.record(req.rid, "prefill_chunk", start=start, tokens=valid)
-                if req.prefilled == req.prefill_len:
+                self.params, self.pool_state, *args)
+            with tel.span("engine/schedule"):
+                self._m_bucket[(C, G)].inc()
+                done = []
+                for j, (req, start, valid) in enumerate(batch.segments):
+                    req.prefilled += valid
+                    self.scheduler.register_prefilled(req)
+                    self.seq_lens = self.seq_lens.at[req.slot].set(
+                        req.prefilled)
+                    self._m_prefill_chunks.inc()
+                    tel.record(req.rid, "prefill_chunk", start=start,
+                               tokens=valid)
+                    if req.prefilled == req.prefill_len:
+                        done.append((j, req))
+            with tel.span("engine/emit"):
+                for j, req in done:
                     # prefill complete: segment j's logits yield the next
                     # token (the request's FIRST, unless this is a resumed
                     # re-prefill continuing an interrupted generation)
+                    tel.record(req.rid, "decode_token" if req.got_first
+                               else "first_token")
                     self._record_token(req, greedy, j, logits, j, sync_memo)
                     emitted.append(req.rid)
-                    if tel.enabled:
-                        if req.got_first:
-                            tel.record(req.rid, "decode_token")
-                        else:
-                            t_first = tel.record(req.rid, "first_token")
-                            t_arrive = tel.tracer.first(req.rid, "arrive")
-                            if t_arrive is not None:
-                                self._h_ttft.observe(t_first - t_arrive)
                     req.got_first = True
                     req.state = DECODING
                     self.active = self.active.at[req.slot].set(True)
                     if req.done:
                         self._finish(req)
 
-        if pol is not None:
-            self._grow_decode()
-        batch = self.scheduler.decode_batch()
+        with tel.span("engine/schedule"):
+            if pol is not None:
+                self._grow_decode()
+            batch = self.scheduler.decode_batch()
         if batch and self._verify is not None:
             emitted.extend(self._spec_decode(batch, sync_memo))
         elif batch:
@@ -656,31 +618,91 @@ class Engine:
                 "engine/decode", self._decode,
                 self.params, self.pool_state, self.next_tok, self.tables,
                 self.seq_lens, self.active)
-            self.next_tok = greedy
-            self._m_decode_steps.inc()
-            self._m_occupancy.inc(len(batch) / e.max_slots)
-            for req in batch:
-                self._record_token(req, greedy, req.slot, logits, req.slot,
-                                   sync_memo)
-                emitted.append(req.rid)
-                tel.record(req.rid, "decode_token")
-                if req.done:
-                    self._finish(req)
 
-        self._m_emitted.inc(len(emitted))
-        if tel.enabled:
-            self._g_waiting.set(len(self.scheduler.waiting))
-            self._g_running.set(len(self.scheduler.running))
-            self._g_free_blocks.set(self.block_pool.num_free)
-            if tel.step_timing:
-                total = tel.clock() - t_step
-                tel.record_step(
-                    host_s=total - self._step_device_s,
-                    device_s=self._step_device_s, prefills=n_prefills,
-                    decode_batch=len(batch), emitted=len(emitted))
-        if pol is not None:
-            pol.note_step(tel.clock() - t_wall)
+        with tel.span("engine/emit"):
+            if batch and self._verify is None:
+                self.next_tok = greedy
+                self._m_decode_steps.inc()
+                self._m_occupancy.inc(len(batch) / e.max_slots)
+                for req in batch:
+                    tel.record(req.rid, "decode_token")
+                    self._record_token(req, greedy, req.slot, logits,
+                                       req.slot, sync_memo)
+                    emitted.append(req.rid)
+                    if req.done:
+                        self._finish(req)
+            self._m_emitted.inc(len(emitted))
+            if tel.enabled:
+                self._g_waiting.set(len(self.scheduler.waiting))
+                self._g_running.set(len(self.scheduler.running))
+                self._g_free_blocks.set(self.block_pool.num_free)
+            if pol is not None:
+                pol.note_step(tel.clock() - t_wall)
         return emitted
+
+    def _allow_prefill(self) -> bool:
+        """Oversubscription's gate before admission: priority preemption,
+        then whether the SLO policy lets this step admit and prefill."""
+        pol = self._policy
+        if pol is None:
+            return True
+        if pol.cfg.priority_preemption:
+            self._priority_preempt()
+        head_wait = None
+        if self.scheduler.waiting:
+            head = self.scheduler.waiting[0]
+            if head.arrive_t is not None:
+                head_wait = pol.clock() - head.arrive_t
+        decoding = sum(1 for r in self.scheduler.running.values()
+                       if r.state == DECODING)
+        allow = pol.allow_prefill(
+            head_wait_s=head_wait, decoding=decoding,
+            pool_util=self.block_pool.utilization)
+        if not allow:
+            self._m_prefill_deferrals.inc()
+        return allow
+
+    def _seat(self, req: Request):
+        """Host side of one admission (its slot's recurrent rows are already
+        zeroed): the block-table row, lifecycle events, a snapshot resume,
+        the slot's sequence length. Returns the (src, dst) blocks of the
+        copy-on-write a fully cached prompt needs, else None."""
+        e = self.ecfg
+        tel = self.telemetry
+        row = self.block_pool.table(req.rid)
+        padded = np.zeros((e.max_blocks_per_seq,), np.int32)
+        padded[:len(row)] = row
+        self.tables = self.tables.at[req.slot].set(jnp.asarray(padded))
+        self._m_prefix_hits.inc(req.prefilled)
+        resumed = req.preempts > 0
+        if tel.enabled:
+            t_admit = tel.record(req.rid, "resume" if resumed else "admit",
+                                 slot=req.slot)
+            if not resumed:
+                t_arrive = tel.tracer.first(req.rid, "arrive")
+                if t_arrive is not None:
+                    self._h_queue_wait.observe(t_admit - t_arrive)
+            if req.prefilled:
+                tel.record(req.rid, "prefix_hit", tokens=req.prefilled,
+                           blocks=req.shared_blocks
+                           + (1 if req.cow_src is not None else 0))
+        if resumed:
+            self._m_resumes.inc()
+        if req.snapshot is not None and self._snapshot_resume:
+            # pure-recurrent resume: scatter the checkpointed slab rows
+            # back into the (freshly zeroed) slot and skip the re-scan —
+            # prefill only covers the tokens past the snapshot
+            self.pool_state = {
+                f"l{i}": p.resume_restore(
+                    self.pool_state[f"l{i}"], req.slot, req.snapshot[i])
+                for i, p in enumerate(self.providers)}
+            req.prefilled = req.snapshot_len
+        req.snapshot = None
+        req.snapshot_len = 0
+        self.seq_lens = self.seq_lens.at[req.slot].set(req.prefilled)
+        if req.cow_src is None:
+            return None
+        return req.cow_src, row[req.prefill_len // e.block_size - 1]
 
     def drain(self, max_steps: int = 100_000) -> dict:
         """Run steps until every queued request finished; returns
@@ -700,17 +722,36 @@ class Engine:
         return self._materialize(self.requests[rid], {})
 
     def _materialize(self, req: Request, memo: dict) -> np.ndarray:
-        out = []
-        for t in req.out_tokens:
-            if isinstance(t, tuple):                # (step vector, index)
-                vec, i = t
-                host = memo.get(id(vec))
-                if host is None:
-                    host = memo[id(vec)] = np.asarray(vec)
-                out.append(int(host[i]))
-            else:
-                out.append(int(t))
-        return np.asarray(out, np.int32)
+        """The request's generated tokens on the host. Each (step vector,
+        index) ref is read — one transfer per vector per ``memo``, which
+        maps id(vector) to (vector, host copy) and so keeps the vector, and
+        its id, alive — and replaced by its value, and the request records
+        ``deliver`` for the tokens that reached the host."""
+        refs = [i for i, t in enumerate(req.out_tokens)
+                if isinstance(t, tuple)]
+        if refs:
+            with self.telemetry.span("engine/sync"):
+                for i in refs:
+                    vec, j = req.out_tokens[i]
+                    if id(vec) not in memo:
+                        memo[id(vec)] = (vec, np.asarray(vec))
+                    req.out_tokens[i] = int(memo[id(vec)][1][j])
+            self._delivered(req, len(refs))
+        return np.asarray(req.out_tokens, np.int32)
+
+    def _delivered(self, req: Request, n: int) -> None:
+        """``n`` more of the request's token values are on the host: record
+        ``deliver``; the first one closes the request's TTFT."""
+        first = req.delivered == 0
+        req.delivered += n
+        tel = self.telemetry
+        if not tel.enabled:
+            return
+        t = tel.record(req.rid, "deliver", tokens=n)
+        if first:
+            t_arrive = tel.tracer.first(req.rid, "arrive")
+            if t_arrive is not None:
+                self._h_ttft.observe(t - t_arrive)
 
     def defragment(self) -> np.ndarray:
         """Compact used KV blocks to the front of the pool and rewrite every
@@ -788,10 +829,8 @@ class Engine:
         order matters: materialize its lazy token refs (the step vectors are
         unreachable after the slot turns over), snapshot recurrent slabs if
         every provider supports restore, deactivate the slot, then let the
-        scheduler register + free its blocks and requeue it. Materialization
-        uses a private memo: this call drops the victim's step-vector refs,
-        so a shared id()-keyed memo could dangle for the rest of the step."""
-        req.out_tokens = [int(t) for t in self._materialize(req, {})]
+        scheduler register + free its blocks and requeue it."""
+        self._materialize(req, {})
         if self._snapshot_resume:
             # state covers exactly the tokens processed as inputs so far:
             # seq_tokens - 1 while DECODING (the last generated token is the
@@ -842,59 +881,65 @@ class Engine:
         tel = self.telemetry
         k = e.spec.k
         emitted = []
-        tokens = np.zeros((e.max_slots, k), np.int32)
-        qlims = np.zeros((e.max_slots,), np.int32)
-        plans = []
-        for req in batch:
-            # drafting needs the concrete stream: materialize any lazy
-            # step-vector refs (at most this step's prefill-completion token)
-            if any(isinstance(t, tuple) for t in req.out_tokens):
-                req.out_tokens = [int(t) for t in
-                                  self._materialize(req, sync_memo)]
-            q = (1 if req.temperature > 0.0
-                 else min(k, req.max_new - len(req.out_tokens)))
-            ctx = np.concatenate([req.prompt,
-                                  np.asarray(req.out_tokens, np.int32)])
-            tokens[req.slot, 0] = ctx[-1]
-            if q > 1:
-                tokens[req.slot, 1:] = self.drafter.propose(
-                    req.rid, ctx, k - 1)
-            qlims[req.slot] = q
-            plans.append((req, q))
+        with tel.span("engine/schedule"):
+            tokens = np.zeros((e.max_slots, k), np.int32)
+            qlims = np.zeros((e.max_slots,), np.int32)
+            plans = []
+            for req in batch:
+                # drafting needs the concrete stream: materialize any lazy
+                # step-vector refs (at most this step's prefill-completion
+                # token)
+                self._materialize(req, sync_memo)
+                q = (1 if req.temperature > 0.0
+                     else min(k, req.max_new - len(req.out_tokens)))
+                ctx = np.concatenate([req.prompt,
+                                      np.asarray(req.out_tokens, np.int32)])
+                tokens[req.slot, 0] = ctx[-1]
+                if q > 1:
+                    tokens[req.slot, 1:] = self.drafter.propose(
+                        req.rid, ctx, k - 1)
+                qlims[req.slot] = q
+                plans.append((req, q))
+            args = (jnp.asarray(tokens), self.tables, self.seq_lens,
+                    self.active, jnp.asarray(qlims))
         greedy, accepts, logits, self.seq_lens, self.pool_state = \
-            self._device_call(
-                "engine/verify", self._verify,
-                self.params, self.pool_state, jnp.asarray(tokens),
-                self.tables, self.seq_lens, self.active, jnp.asarray(qlims))
-        g_host = np.asarray(greedy)
-        a_host = np.asarray(accepts)
-        self._m_step_syncs.inc()
-        self._m_decode_steps.inc()
-        self._m_verify_steps.inc()
-        self._m_occupancy.inc(len(batch) / e.max_slots)
-        for req, q in plans:
-            a = int(a_host[req.slot])
-            toks = [int(t) for t in g_host[req.slot, :a]]
-            if req.temperature > 0.0:
-                req.key, sub = jax.random.split(req.key)
-                toks = [int(jax.random.categorical(
-                    sub, logits[req.slot, 0] / req.temperature))]
-            if req.stop_token is not None and req.stop_token in toks:
-                # truncate at the stop token; the device advanced past it
-                # but the slot is freed below, so the overrun is unreachable
-                toks = toks[:toks.index(req.stop_token) + 1]
-            req.out_tokens.extend(toks)
-            emitted.append(req.rid)
-            drafted, accepted = max(q - 1, 0), max(a - 1, 0)
-            self._m_draft.inc(drafted)
-            self._m_accepted.inc(accepted)
-            if drafted:
-                self._h_accept.observe(accepted / drafted)
-            tel.record(req.rid, "verify", drafted=drafted, accepted=accepted)
-            tel.record(req.rid, "decode_token", tokens=len(toks))
-            self._m_emitted.inc(len(toks) - 1)    # step() adds 1 per rid
-            if req.done:
-                self._finish(req)
+            self._device_call("engine/verify", self._verify,
+                              self.params, self.pool_state, *args)
+        with tel.span("engine/sync"):
+            g_host = np.asarray(greedy)
+            a_host = np.asarray(accepts)
+        with tel.span("engine/emit"):
+            self._m_step_syncs.inc()
+            self._m_decode_steps.inc()
+            self._m_verify_steps.inc()
+            self._m_occupancy.inc(len(batch) / e.max_slots)
+            for req, q in plans:
+                a = int(a_host[req.slot])
+                toks = [int(t) for t in g_host[req.slot, :a]]
+                if req.temperature > 0.0:
+                    req.key, sub = jax.random.split(req.key)
+                    with tel.span("engine/sync"):
+                        toks = [int(jax.random.categorical(
+                            sub, logits[req.slot, 0] / req.temperature))]
+                if req.stop_token is not None and req.stop_token in toks:
+                    # truncate at the stop token; the device advanced past
+                    # it but the slot is freed below, so the overrun is
+                    # unreachable
+                    toks = toks[:toks.index(req.stop_token) + 1]
+                req.out_tokens.extend(toks)
+                emitted.append(req.rid)
+                drafted, accepted = max(q - 1, 0), max(a - 1, 0)
+                self._m_draft.inc(drafted)
+                self._m_accepted.inc(accepted)
+                if drafted:
+                    self._h_accept.observe(accepted / drafted)
+                tel.record(req.rid, "verify", drafted=drafted,
+                           accepted=accepted)
+                tel.record(req.rid, "decode_token", tokens=len(toks))
+                self._delivered(req, len(toks))
+                self._m_emitted.inc(len(toks) - 1)    # step() adds 1 per rid
+                if req.done:
+                    self._finish(req)
         return emitted
 
     def _spec_horizon(self, req: Request) -> int:
@@ -913,20 +958,25 @@ class Engine:
         (one dict per engine step) caches materialized step vectors so
         stop_token scanning costs at most ONE transfer per step vector, not
         one per request."""
+        tel = self.telemetry
         if req.temperature > 0.0:
             req.key, sub = jax.random.split(req.key)
-            tok = int(jax.random.categorical(
-                sub, logits[logits_idx] / req.temperature))
+            with tel.span("engine/sync"):
+                tok = int(jax.random.categorical(
+                    sub, logits[logits_idx] / req.temperature))
             self.next_tok = self.next_tok.at[req.slot].set(tok)
             req.out_tokens.append(tok)
+            self._delivered(req, 1)
             return
         if req.stop_token is not None:
-            host = sync_memo.get(id(greedy_vec))
-            if host is None:
-                host = sync_memo[id(greedy_vec)] = np.asarray(greedy_vec)
+            if id(greedy_vec) not in sync_memo:
+                with tel.span("engine/sync"):
+                    sync_memo[id(greedy_vec)] = (greedy_vec,
+                                                 np.asarray(greedy_vec))
                 self._m_step_syncs.inc()
-            tok = int(host[greedy_idx])
-            req.out_tokens.append(tok)
+            host = sync_memo[id(greedy_vec)][1]
+            req.out_tokens.append(int(host[greedy_idx]))
+            self._delivered(req, 1)
         else:
             req.out_tokens.append((greedy_vec, greedy_idx))
         if req.state != DECODING:

@@ -126,6 +126,8 @@ class Request:
     got_first: bool = False             # first_token already emitted (so a
                                         #   resumed prefill completion is an
                                         #   ordinary decode_token)
+    delivered: int = 0                  # generated tokens whose values have
+                                        #   reached the host
     prefill_tokens: Optional[np.ndarray] = None   # resume: prompt + generated
     snapshot: Optional[list] = None     # per-layer provider state snapshot
     snapshot_len: int = 0               # tokens the snapshot state covers

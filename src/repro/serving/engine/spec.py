@@ -41,6 +41,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Protocol, runtime_checkable
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -216,7 +217,8 @@ def verify_step(cfg, params, pool, tokens, block_tables, seq_lens, active,
     qlims = jnp.where(active, qlims, 0)
     lg, aux = T.paged_verify_step(cfg, params, pool, tokens, block_tables,
                                   base, qlims, impl=impl)
-    greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)            # (B, K)
+    with jax.named_scope("head"):
+        greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)        # (B, K)
     match = (tokens[:, 1:] == greedy[:, :-1]).astype(jnp.int32)   # (B, K-1)
     run = jnp.cumprod(match, axis=1) if match.shape[1] else match
     accepts = 1 + jnp.sum(run, axis=1)

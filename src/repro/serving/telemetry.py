@@ -18,10 +18,13 @@ Four pieces, composable but independently usable:
     ~1/cap after).
   * ``RequestTracer`` — append-only event log of per-request lifecycle
     events (``arrive``/``admit``/``prefix_hit``/``prefill_chunk``/
-    ``first_token``/``decode_token``/``evict``/``defrag``/``finish``) with
-    monotonic ``time.perf_counter`` timestamps, so TTFT, queue wait, and
-    per-phase latency are *derived* (``derive_timeline``) rather than
-    guessed.
+    ``first_token``/``decode_token``/``deliver``/``evict``/``defrag``/
+    ``finish``) with monotonic ``time.perf_counter`` timestamps and the
+    engine step they belong to, so TTFT, queue wait, and per-phase latency
+    are *derived* (``derive_timeline``) rather than guessed.
+    ``first_token`` and ``decode_token`` mark when a token's step was
+    DISPATCHED; ``deliver`` marks when token values reached the host, which
+    is what a client sees.
   * ``RecompileTracker`` — wraps jitted step functions and counts unique
     (function, arg shapes/dtypes) trace keys: the number of distinct
     compiled step variants a serving run dispatched, the precursor metric
@@ -33,7 +36,10 @@ Four pieces, composable but independently usable:
 ``Telemetry`` bundles the four behind one ``enabled`` switch
 (``EngineConfig.telemetry``): when disabled every record call is a cheap
 early return, no events are stored, and engine outputs are unchanged —
-telemetry never touches device code, only host bookkeeping around it.
+telemetry never touches device code, only host bookkeeping around it. Its
+``span``s are ``jax.profiler.TraceAnnotation``s: under the profiler they
+land on the device trace's clock, so each idle gap of the device can be
+named by the host work that covers it.
 
 Metric naming scheme (see the engine README's Telemetry section):
 ``<subsystem>_<quantity>_<unit>`` with ``_total`` for counters and
@@ -57,13 +63,17 @@ import numpy as np
 # in ``validate_order`` resets at each ``resume``. ``verify`` is the
 # speculative-decoding acceptance record (drafted/accepted counts); it ranks
 # WITH ``decode_token`` — each verify step emits both, in either order.
+# ``deliver`` (``tokens=n`` values reached the host) ranks with
+# ``first_token`` but runs beside the rank machine: it may follow any event
+# after the first token's dispatch, ``finish`` included, since a finished
+# request's last tokens are often read only afterwards.
 EVENTS = ("arrive", "admit", "prefix_hit", "prefill_chunk", "first_token",
-          "verify", "decode_token", "preempt", "resume", "evict", "defrag",
-          "finish")
+          "verify", "decode_token", "deliver", "preempt", "resume", "evict",
+          "defrag", "finish")
 
 _LIFECYCLE_RANK = {"arrive": 0, "admit": 1, "resume": 1, "prefix_hit": 2,
-                   "prefill_chunk": 3, "first_token": 4, "verify": 5,
-                   "decode_token": 5, "preempt": 6, "finish": 7}
+                   "prefill_chunk": 3, "first_token": 4, "deliver": 4,
+                   "verify": 5, "decode_token": 5, "preempt": 6, "finish": 7}
 _ONCE = ("arrive", "admit", "first_token", "finish")
 
 
@@ -256,19 +266,25 @@ class Event(NamedTuple):
     rid: Optional[int]          # None for pool-wide events (evict/defrag)
     name: str
     data: Optional[dict]
+    step: Optional[int] = None  # engine step during (or after) which it
+                                #   was recorded; None before the first
 
 
 class RequestTracer:
-    """Append-only lifecycle event log, indexed globally and per request."""
+    """Append-only lifecycle event log, indexed globally and per request.
+    ``step`` is stamped on every event; the engine sets it at the start of
+    each step, so an event joins the profiler's ``engine/step`` span that
+    carries the same ``step``."""
 
     def __init__(self, clock: Callable[[], float] = time.perf_counter):
         self.clock = clock
         self.events: list = []
         self._by_rid: dict = {}
+        self.step: Optional[int] = None
 
     def record(self, rid, name: str, **data) -> float:
         t = self.clock()
-        ev = Event(t, rid, name, data or None)
+        ev = Event(t, rid, name, data or None, self.step)
         self.events.append(ev)
         if rid is not None:
             self._by_rid.setdefault(rid, []).append(ev)
@@ -289,19 +305,22 @@ class RequestTracer:
 
 def derive_timeline(events) -> dict:
     """Fold one request's event stream into its derived timeline: TTFT =
-    ``first_token - arrive``, queue wait = ``admit - arrive``, end-to-end =
-    ``finish - arrive``, the per-token decode timeline, and the preemption
-    view — ``preempts`` (rollback count) and ``preempted_s`` (total time
-    spent evicted, summed over matched preempt→resume pairs; a stream that
-    ends while still evicted contributes its open interval up to the last
-    event's timestamp). Speculative decoding: a ``decode_token`` event may
+    first ``deliver`` - ``arrive`` (the first token's value on the host;
+    ``first_token`` keeps its dispatch time), queue wait = ``admit -
+    arrive``, end-to-end = ``finish - arrive``, the per-token dispatch
+    (``decode_tokens``) and delivery (``delivered``) timelines, and the
+    preemption view — ``preempts`` (rollback count) and ``preempted_s``
+    (total time spent evicted, summed over matched preempt→resume pairs; a
+    stream that ends while still evicted contributes its open interval up
+    to the last event's timestamp). Speculative decoding: a ``decode_token`` event may
     carry ``tokens=n`` (the accepted run of one verify step) — the decode
     timeline counts every ACCEPTED token, n entries at that timestamp, so
     TPOT statistics stay per-token rather than per-engine-step; drafted /
     accepted totals are summed from the ``verify`` events."""
     tl = {"events": list(events), "arrive": None, "admit": None,
-          "first_token": None, "finish": None, "prefill_chunks": 0,
-          "decode_tokens": [], "prefix_hit_tokens": 0,
+          "first_token": None, "deliver": None, "finish": None,
+          "prefill_chunks": 0, "decode_tokens": [], "delivered": [],
+          "prefix_hit_tokens": 0,
           "preempts": 0, "preempted_s": 0.0,
           "draft_tokens": 0, "accepted_tokens": 0}
     pend = None                        # open preempt awaiting its resume
@@ -313,6 +332,10 @@ def derive_timeline(events) -> dict:
         elif ev.name == "decode_token":
             tl["decode_tokens"].extend(
                 [ev.t] * (ev.data or {}).get("tokens", 1))
+        elif ev.name == "deliver":
+            if tl["deliver"] is None:
+                tl["deliver"] = ev.t
+            tl["delivered"].extend([ev.t] * (ev.data or {}).get("tokens", 1))
         elif ev.name == "verify":
             tl["draft_tokens"] += (ev.data or {}).get("drafted", 0)
             tl["accepted_tokens"] += (ev.data or {}).get("accepted", 0)
@@ -330,7 +353,7 @@ def derive_timeline(events) -> dict:
     if pend is not None and events:
         tl["preempted_s"] += events[-1].t - pend
     for key, a, b in (("queue_wait", "arrive", "admit"),
-                      ("ttft", "arrive", "first_token"),
+                      ("ttft", "arrive", "deliver"),
                       ("e2e", "arrive", "finish")):
         tl[key] = (tl[b] - tl[a]
                    if tl[a] is not None and tl[b] is not None else None)
@@ -348,8 +371,10 @@ def validate_order(events) -> None:
     floor so the request re-runs prefix_hit / prefill_chunk / decode_token
     phases; ``resume`` without an open ``preempt`` is an error. One-shot
     events stay globally one-shot across segments (``first_token`` fires in
-    whichever segment first completes prefill). Raises ``TelemetryError``
-    with the offending pair."""
+    whichever segment first completes prefill). ``deliver`` needs an
+    earlier ``first_token`` and may come after ``finish`` (nothing else
+    may), but never while evicted; it leaves the rank floor where it is.
+    Raises ``TelemetryError`` with the offending pair."""
     if not events:
         raise TelemetryError("empty event stream")
     names = [e.name for e in events]
@@ -358,10 +383,12 @@ def validate_order(events) -> None:
             raise TelemetryError(f"duplicate {n!r} event")
     if names[0] != "arrive":
         raise TelemetryError(f"stream starts with {names[0]!r}, not 'arrive'")
-    if "finish" in names and names[-1] != "finish":
+    lifecycle = [n for n in names if n != "deliver"]
+    if "finish" in names and lifecycle[-1] != "finish":
         raise TelemetryError("events recorded after 'finish'")
     floor = _LIFECYCLE_RANK["arrive"]
     evicted = False
+    first = False
     prev = events[0]
     for ev in events[1:]:
         if ev.t < prev.t:
@@ -371,7 +398,13 @@ def validate_order(events) -> None:
         rank = _LIFECYCLE_RANK.get(ev.name)
         if rank is None:
             raise TelemetryError(f"unknown lifecycle event {ev.name!r}")
-        if evicted:
+        first = first or ev.name == "first_token"
+        if ev.name == "deliver":
+            if not first or evicted:
+                raise TelemetryError(
+                    "'deliver' before 'first_token'" if not first else
+                    "'deliver' recorded while evicted")
+        elif evicted:
             if ev.name != "resume":
                 raise TelemetryError(
                     f"{ev.name!r} recorded while evicted (preempt without "
@@ -459,31 +492,17 @@ _NULL_SPAN = _NullSpan()
 
 
 class Telemetry:
-    """One serving stack's telemetry: registry + tracer + recompile tracker
-    + engine-step timeline, behind a single ``enabled`` switch.
+    """One serving stack's telemetry: registry + tracer + recompile tracker,
+    behind a single ``enabled`` switch."""
 
-    ``step_timing`` additionally blocks on device results inside the engine's
-    timed path so each step's host/device split is real compute time, not
-    async dispatch (mirrors serving_bench's latency pass); it is off by
-    default because blocking serializes the host-ahead pipeline.
-    """
-
-    def __init__(self, enabled: bool = True, step_timing: bool = False,
+    def __init__(self, enabled: bool = True,
                  registry: Optional[MetricsRegistry] = None,
                  clock: Callable[[], float] = time.perf_counter):
         self.enabled = bool(enabled)
-        self.step_timing = bool(step_timing) and self.enabled
         self.clock = clock
         self.registry = registry if registry is not None else MetricsRegistry()
         self.tracer = RequestTracer(clock=clock)
         self.recompiles = RecompileTracker(self.registry)
-        self.steps: list = []           # per-step dicts (step_timing only)
-        self._h_host = self.registry.histogram(
-            "engine_step_host_seconds",
-            "per-step host scheduling time (step_timing runs only)")
-        self._h_dev = self.registry.histogram(
-            "engine_step_device_seconds",
-            "per-step blocked device time (step_timing runs only)")
 
     # -- recording (no-ops when disabled) --------------------------------
     def record(self, rid, event: str, **data) -> Optional[float]:
@@ -491,20 +510,14 @@ class Telemetry:
             return None
         return self.tracer.record(rid, event, **data)
 
-    def span(self, name: str):
-        """`jax.profiler.TraceAnnotation` span so device traces are labeled
-        per phase; a no-op context manager when disabled."""
+    def span(self, name: str, **meta):
+        """`jax.profiler.TraceAnnotation` span, so device traces are labeled
+        per phase on the profiler's own clock; ``meta`` (e.g. ``step=n``)
+        rides along as the event's stats. A no-op context manager when
+        disabled."""
         if not self.enabled:
             return _NULL_SPAN
-        return jax.profiler.TraceAnnotation(name)
-
-    def record_step(self, *, host_s: float, device_s: float, **data) -> None:
-        if not self.step_timing:
-            return
-        self._h_host.observe(host_s)
-        self._h_dev.observe(device_s)
-        self.steps.append({"step": len(self.steps), "host_s": host_s,
-                           "device_s": device_s, **data})
+        return jax.profiler.TraceAnnotation(name, **meta)
 
     # -- views -----------------------------------------------------------
     def request_timeline(self, rid) -> dict:
@@ -517,7 +530,8 @@ class Telemetry:
         per-request timelines."""
         with open(path, "w") as f:
             for ev in self.tracer.events:
-                row = {"t": ev.t, "rid": ev.rid, "event": ev.name}
+                row = {"t": ev.t, "rid": ev.rid, "event": ev.name,
+                       "step": ev.step}
                 if ev.data:
                     row["data"] = ev.data
                 f.write(json.dumps(row) + "\n")
@@ -542,6 +556,7 @@ def replay_jsonl(path) -> dict:
             if rid is None:
                 continue
             by_rid.setdefault(rid, []).append(
-                Event(row["t"], rid, row["event"], row.get("data")))
+                Event(row["t"], rid, row["event"], row.get("data"),
+                      row.get("step")))
     return {rid: derive_timeline(sorted(evs, key=lambda e: e.t))
             for rid, evs in by_rid.items()}

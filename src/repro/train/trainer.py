@@ -96,7 +96,9 @@ def make_train_step(cfg, optimizer, plan, *, donate=True, accum_steps=1):
                 (loss, grads), _ = jax.lax.scan(micro_step, zero, micro)
                 loss = loss / accum_steps
                 grads = jax.tree.map(lambda g: g / accum_steps, grads)
-        new_params, new_opt = optimizer.update(grads, state["opt"], state["params"])
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = optimizer.update(grads, state["opt"],
+                                                   state["params"])
         metrics = {"loss": loss}
         return {"params": new_params, "opt": new_opt}, metrics
 
@@ -144,7 +146,9 @@ def make_paper_train_step(cfg, optimizer, mesh, *, axis="data",
             lambda g: coll.allreduce_mean(g, axis, algorithm=algorithm), grads)
         loss = coll.allreduce_mean(loss, axis, algorithm="psum")
 
-        new_params, new_opt = optimizer.update(grads, state["opt"], state["params"])
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = optimizer.update(grads, state["opt"],
+                                                   state["params"])
         return {"params": new_params, "opt": new_opt}, {"loss": loss}, residual
 
     pspec_state = jax.tree.map(lambda _: P(), {"dummy": 0})  # built below

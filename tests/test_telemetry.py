@@ -1,7 +1,9 @@
 """Serving telemetry: metrics registry (streaming quantiles vs np.percentile),
 request-lifecycle event ordering, recompile tracking (unique trace keys),
-step-timeline host/device split, exporters (JSONL replay + Prometheus text),
-and the disabled-mode guarantees (no events, bit-identical greedy outputs).
+the engine's profiler spans (every step covered by its children) and the
+named scopes of the compiled programs, exporters (JSONL replay + Prometheus
+text), and the disabled-mode guarantees (no events, bit-identical greedy
+outputs).
 All CPU (`-m telemetry`, subset of `-m serving`)."""
 import math
 
@@ -118,6 +120,9 @@ class TestTracerValidation:
         (("arrive", "first_token", "admit"), "order"),
         (("arrive", "admit", "arrive"), "duplicate"),
         (("arrive", "finish", "decode_token"), "finish"),
+        (("arrive", "admit", "prefill_chunk", "deliver"), "first_token"),
+        (("arrive", "admit", "first_token", "preempt", "deliver"),
+         "evicted"),
     ])
     def test_validate_order_rejects(self, names, msg):
         tr = TM.RequestTracer()
@@ -125,6 +130,20 @@ class TestTracerValidation:
             tr.record(0, name)
         with pytest.raises(TM.TelemetryError, match=msg):
             TM.validate_order(tr.request_events(0))
+
+    def test_deliver_may_follow_finish(self):
+        tr = TM.RequestTracer()
+        tr.step = 4
+        for name in ("arrive", "admit", "prefill_chunk", "first_token",
+                     "deliver", "decode_token", "finish", "deliver"):
+            tr.record(0, name, **({"tokens": 1} if name == "deliver"
+                                  else {}))
+        evs = tr.request_events(0)
+        TM.validate_order(evs)
+        assert {e.step for e in evs} == {4}
+        tl = TM.derive_timeline(evs)
+        assert tl["ttft"] == tl["deliver"] - tl["arrive"]
+        assert len(tl["delivered"]) == 2
 
     def test_timestamp_regression_rejected(self):
         evs = [TM.Event(2.0, 0, "arrive", None),
@@ -179,7 +198,12 @@ class TestEngineLifecycle:
             assert tl["arrive"] <= tl["admit"] <= tl["first_token"] \
                 <= tl["finish"]
             assert tl["queue_wait"] >= 0 and tl["ttft"] >= tl["queue_wait"]
-            assert tl["e2e"] >= tl["ttft"]
+            # TTFT ends when the first value reaches the host (here: in
+            # drain, after finish), never before its dispatch
+            assert tl["first_token"] <= tl["deliver"]
+            assert tl["ttft"] == tl["deliver"] - tl["arrive"]
+            assert tl["e2e"] >= tl["first_token"] - tl["arrive"]
+            assert len(tl["delivered"]) == outs[rid].shape[0]
             # token #1 comes from the final prefill chunk's logits; every
             # later token is a decode step
             assert len(tl["decode_tokens"]) == outs[rid].shape[0] - 1 == mn - 1
@@ -301,62 +325,128 @@ class TestRecompileTracker:
         assert eng.telemetry.recompiles.total == 3
 
 
-# ------------------------------------------------------------- step timeline
-class TestStepTimeline:
-    def test_step_timing_records_host_device_split(self, cfg, params):
-        prompts, news = _requests(seed=15)
-        eng = _engine(cfg, params, step_timing=True)
-        for p, mn in zip(prompts, news):
-            eng.add_request(p, mn)
-        steps = 0
+# ------------------------------------------------------------ profiler spans
+STEP_CHILDREN = {"engine/schedule", "engine/prefill", "engine/decode",
+                 "engine/verify", "engine/copy_block", "engine/reset_slot",
+                 "engine/emit"}
+
+
+def _engine_spans(logdir):
+    """(start, end, name, stats) of every ``engine/*`` host annotation in
+    the profile written under ``logdir``, by start."""
+    import glob
+    from jax.profiler import ProfileData
+    path, = glob.glob(f"{logdir}/**/*.xplane.pb", recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("engine/"):
+                    out.append((ev.start_ns, ev.end_ns, ev.name,
+                                dict(ev.stats)))
+    return sorted(out, key=lambda s: (s[0], -s[1]))
+
+
+class TestProfilerSpans:
+    def test_step_spans_cover_each_step(self, cfg, params, tmp_path):
+        """Under the profiler every engine step is one ``engine/step`` span
+        carrying the engine's step number, whose direct children are the
+        named phases, never overlap, and leave little of it uncovered; each
+        lifecycle event carries the step it was recorded in."""
+        prompts, news = _requests(seed=31)
+        eng = _engine(cfg, params)
+        eng.add_request(prompts[0], 2)
+        eng.drain()                                 # compiles off the trace
+        first = eng.step_count
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            rids = []
+            for i, (p, mn) in enumerate(zip(prompts, news)):
+                rids.append(eng.add_request(
+                    p, mn, stop_token=7 if i % 2 else None))
+                eng.step()
+            outs = eng.drain()
+        finally:
+            jax.profiler.stop_trace()
+        spans = _engine_spans(tmp_path)
+        steps = [s for s in spans if s[2] == "engine/step"]
+        assert [int(s[3]["step"]) for s in steps] == list(
+            range(first, eng.step_count))
+        total = uncovered = 0
+        for a, b, _, _ in steps:
+            inner = [s for s in spans if s[2] != "engine/step"
+                     and a <= s[0] and s[1] <= b]
+            kids = [k for k in inner if not any(
+                o is not k and o[0] <= k[0] and k[1] <= o[1] for o in inner)]
+            names = {k[2] for k in kids}
+            assert names <= STEP_CHILDREN
+            assert {"engine/schedule", "engine/emit"} <= names
+            for x, y in zip(kids, kids[1:]):
+                assert x[1] <= y[0]                 # children never overlap
+            self_ns = (b - a) - sum(k[1] - k[0] for k in kids)
+            assert self_ns >= 0
+            assert sum(k[1] - k[0] for k in kids) + self_ns == b - a
+            total += b - a
+            uncovered += self_ns
+        # what no child covers is the spans' own entry and exit, no more
+        assert uncovered < 0.25 * total
+        names = [s[2] for s in spans]
+        assert names.count("engine/add_request") == len(prompts)
+        assert "engine/sync" in names               # stop tokens, drain
+        traced = set(range(first, eng.step_count))
+        for rid in rids:
+            evs = eng.telemetry.tracer.request_events(rid)
+            TM.validate_order(evs)
+            assert {e.step for e in evs if e.name not in (
+                "arrive", "deliver")} <= traced
+            tl = TM.derive_timeline(evs)
+            assert len(tl["delivered"]) == outs[rid].shape[0]
+
+    def test_ttft_histogram_counts_deliveries(self, cfg, params):
+        """``engine_request_ttft_seconds`` closes at the first token's
+        delivery: not at dispatch, and once per request."""
+        prompts, news = _requests(n=3, seed=37)
+        eng = _engine(cfg, params)
+        rids = [eng.add_request(p, mn) for p, mn in zip(prompts, news)]
         while eng.scheduler.has_work:
             eng.step()
-            steps += 1
-        assert len(eng.telemetry.steps) == steps > 0
-        for entry in eng.telemetry.steps:
-            assert entry["host_s"] >= 0 and entry["device_s"] > 0
-        reg = eng.telemetry.registry
-        assert reg.get("engine_step_host_seconds").count == steps
-        assert reg.get("engine_step_device_seconds").count == steps
-
-    def test_throughput_mode_skips_timeline(self, cfg, params):
-        prompts, news = _requests(n=2, seed=17)
-        eng = _engine(cfg, params)                  # step_timing off
-        for p, mn in zip(prompts, news):
-            eng.add_request(p, mn)
+        h = eng.telemetry.registry.get("engine_request_ttft_seconds")
+        assert h.count == 0                         # dispatched, not read
+        eng.output(rids[0])
+        eng.output(rids[0])                         # read once, counted once
+        assert h.count == 1
         eng.drain()
-        assert eng.telemetry.steps == []
-        assert eng.telemetry.registry.get("engine_step_host_seconds").count == 0
+        assert h.count == len(rids)
+        for rid in rids:
+            tl = eng.telemetry.request_timeline(rid)
+            assert tl["ttft"] == tl["deliver"] - tl["arrive"]
+            assert tl["deliver"] > tl["first_token"]
 
 
 # ----------------------------------------------------- disabled mode + equality
 class TestDisabledMode:
     def test_disabled_records_nothing_and_outputs_identical(self, cfg, params):
         """Acceptance: greedy outputs are bit-identical to serve.generate
-        with telemetry on, off, and in the blocking timing path."""
+        with telemetry on and off."""
         prompts, news = _requests(seed=19)
         outs = {}
-        for mode, kw in (("on", {}), ("off", {"telemetry": False}),
-                         ("timing", {"step_timing": True})):
+        for mode, kw in (("on", {}), ("off", {"telemetry": False})):
             eng = _engine(cfg, params, **kw)
             rids = [eng.add_request(p, mn) for p, mn in zip(prompts, news)]
             res = eng.drain()
             outs[mode] = [res[r] for r in rids]
             if mode == "off":
                 assert eng.telemetry.tracer.events == []
-                assert eng.telemetry.steps == []
                 assert eng.telemetry.recompiles.total == 0
                 # back-compat stats stay live with telemetry off
                 assert eng.stats["decode_steps"] > 0
                 assert eng.stats["emitted"] == sum(news)
-        for p, mn, a, b, c in zip(prompts, news, outs["on"], outs["off"],
-                                  outs["timing"]):
+        for p, mn, a, b in zip(prompts, news, outs["on"], outs["off"]):
             ref = np.asarray(serve.generate(
                 cfg, params, jnp.asarray(p)[None], max_new=mn,
                 temperature=0.0))[0]
             np.testing.assert_array_equal(a, ref)
             np.testing.assert_array_equal(b, ref)
-            np.testing.assert_array_equal(c, ref)
 
     def test_pool_stats_backcompat_standalone(self):
         from repro.serving.engine import BlockPool
