@@ -127,10 +127,10 @@ def make_prompts(vocab, seed, lens=PROMPT_LENS, shared=SHARED_PREFIX):
 
 
 def smoke_engine_config(lens=PROMPT_LENS, new_tokens=NEW_TOKENS):
-    """Pool sized for phi4-mini on one 16 GB chip: the decode and prefill
-    steps hold a second copy of the pool as a temporary (the scan over
-    (blocks, pool) in models/transformer.py), so params + 2 x pool must fit.
-    1024 blocks of 16 tokens are 2.15 GB in bf16."""
+    """Pool for phi4-mini on one 16 GB chip: 1024 blocks of 16 tokens,
+    2.15 GB in bf16. The decode and prefill steps write it in place (the
+    layer scan carries it, models/transformer.py), so params + pool must
+    fit."""
     from repro.serving.engine import EngineConfig
     per_seq = -(-(max(lens) + new_tokens) // 16)
     return EngineConfig(block_size=16, num_blocks=1024,

@@ -68,17 +68,25 @@ def _masked_gqa_attend_multi(q, k, v, valid, scale):
     return out.reshape(B, K, H, hd).astype(q.dtype)
 
 
-def _gather_pool(pool, scl, tables, T):
+def _blocks(pool, tables, layer=None):
+    """Pool blocks through a block table: (B, P, bs, ...). ``pool`` is one
+    layer's (N, bs, ...) with ``layer=None``, else the layers' stack
+    (L, N, bs, ...), read at ``layer`` in the same gather — no layer is
+    sliced out of the stack first."""
+    return pool[tables] if layer is None else pool[layer, tables]
+
+
+def _gather_pool(pool, scl, tables, T, layer=None):
     """Gather pool blocks through a block table into (B, T, Hkv, hd) f32.
     When ``scl`` (N, bs, Hkv) is given the pool is int8 and each vector is
     dequantized with the same per-(slot, head) multiply as the Pallas
     kernel's `_load_kv` — so ref-with-scales is bitwise identical to the ref
-    run on a pre-dequantized f32 pool."""
+    run on a pre-dequantized f32 pool. ``layer`` as in :func:`_blocks`."""
     B = tables.shape[0]
-    Hkv, hd = pool.shape[2], pool.shape[3]
-    x = pool[tables].astype(jnp.float32)                 # (B, P, bs, Hkv, hd)
+    Hkv, hd = pool.shape[-2:]
+    x = _blocks(pool, tables, layer).astype(jnp.float32)  # (B, P, bs, Hkv, hd)
     if scl is not None:
-        x = x * scl[tables][..., None]
+        x = x * _blocks(scl, tables, layer)[..., None]
     return x.reshape(B, T, Hkv, hd)
 
 
@@ -96,23 +104,24 @@ def ring_key_positions(positions, ring_pages, block_size):
 
 
 def paged_attention_ref(q, k_pool, v_pool, block_tables, seq_lens, *,
-                        scale=None, window=None, positions=None,
+                        layer=None, scale=None, window=None, positions=None,
                         ring_pages=None, k_scale=None, v_scale=None):
-    """q: (B, H, hd); k_pool/v_pool: (N, bs, Hkv, hd);
-    block_tables: (B, P) int32; seq_lens: (B,) int32. Returns (B, H, hd).
+    """q: (B, H, hd); k_pool/v_pool: (N, bs, Hkv, hd), or the layers' stack
+    (L, N, bs, Hkv, hd) read at ``layer``; block_tables: (B, P) int32;
+    seq_lens: (B,) int32. Returns (B, H, hd).
 
     window/positions/ring_pages switch on ring mode (all three required):
     attend the sliding window (positions - window, positions] through the
-    ring block layout. k_scale/v_scale: int8-pool dequant scales
-    (N, bs, Hkv) f32."""
+    ring block layout. k_scale/v_scale: int8-pool dequant scales, f32,
+    shaped like the pools without hd."""
     B, H, hd = q.shape
-    N, bs, Hkv, _ = k_pool.shape
+    bs = k_pool.shape[-3]
     scale = scale if scale is not None else hd ** -0.5
 
     if window is None:
         P = block_tables.shape[1]
-        k = _gather_pool(k_pool, k_scale, block_tables, P * bs)
-        v = _gather_pool(v_pool, v_scale, block_tables, P * bs)
+        k = _gather_pool(k_pool, k_scale, block_tables, P * bs, layer)
+        v = _gather_pool(v_pool, v_scale, block_tables, P * bs, layer)
         valid = jnp.arange(P * bs)[None, :] < seq_lens[:, None]
         return _masked_gqa_attend(q, k, v, valid, scale)
 
@@ -120,8 +129,8 @@ def paged_attention_ref(q, k_pool, v_pool, block_tables, seq_lens, *,
         raise ValueError("ring mode needs window, positions AND ring_pages")
     R = ring_pages
     tables = block_tables[:, :R]
-    k = _gather_pool(k_pool, k_scale, tables, R * bs)
-    v = _gather_pool(v_pool, v_scale, tables, R * bs)
+    k = _gather_pool(k_pool, k_scale, tables, R * bs, layer)
+    v = _gather_pool(v_pool, v_scale, tables, R * bs, layer)
     kpos = ring_key_positions(positions, R, bs)                   # (B, R*bs)
     valid = ((kpos >= 0)
              & (kpos <= positions[:, None])
@@ -131,8 +140,9 @@ def paged_attention_ref(q, k_pool, v_pool, block_tables, seq_lens, *,
 
 
 def paged_attention_verify_ref(q, k_pool, v_pool, block_tables, seq_lens, *,
-                               scale=None, window=None, positions=None,
-                               ring_pages=None, k_scale=None, v_scale=None):
+                               layer=None, scale=None, window=None,
+                               positions=None, ring_pages=None, k_scale=None,
+                               v_scale=None):
     """Multi-query verify oracle for speculative decoding.
 
     q: (B, K, H, hd) — K draft queries per sequence. ``seq_lens[b]`` counts
@@ -146,16 +156,17 @@ def paged_attention_verify_ref(q, k_pool, v_pool, block_tables, seq_lens, *,
     sliding window ``(qpos - window, qpos]`` through the ring layout. The
     caller is responsible for sizing the ring so that the oldest query's
     window is still resident (``ring_pages(window, bs, draft=K-1)``).
-    Returns (B, K, H, hd)."""
+    Pools, ``layer`` and scales as in :func:`paged_attention_ref`. Returns
+    (B, K, H, hd)."""
     B, K, H, hd = q.shape
-    N, bs, Hkv, _ = k_pool.shape
+    bs = k_pool.shape[-3]
     scale = scale if scale is not None else hd ** -0.5
     qpos = seq_lens[:, None] - K + jnp.arange(K)[None, :]         # (B, K)
 
     if window is None:
         P = block_tables.shape[1]
-        k = _gather_pool(k_pool, k_scale, block_tables, P * bs)
-        v = _gather_pool(v_pool, v_scale, block_tables, P * bs)
+        k = _gather_pool(k_pool, k_scale, block_tables, P * bs, layer)
+        v = _gather_pool(v_pool, v_scale, block_tables, P * bs, layer)
         kpos = jnp.arange(P * bs)
         valid = kpos[None, None, :] <= qpos[:, :, None]           # (B, K, P*bs)
         return _masked_gqa_attend_multi(q, k, v, valid, scale)
@@ -164,8 +175,8 @@ def paged_attention_verify_ref(q, k_pool, v_pool, block_tables, seq_lens, *,
         raise ValueError("ring mode needs window, positions AND ring_pages")
     R = ring_pages
     tables = block_tables[:, :R]
-    k = _gather_pool(k_pool, k_scale, tables, R * bs)
-    v = _gather_pool(v_pool, v_scale, tables, R * bs)
+    k = _gather_pool(k_pool, k_scale, tables, R * bs, layer)
+    v = _gather_pool(v_pool, v_scale, tables, R * bs, layer)
     kpos = ring_key_positions(positions, R, bs)                   # (B, R*bs)
     valid = ((kpos[:, None, :] >= 0)
              & (kpos[:, None, :] <= qpos[:, :, None])

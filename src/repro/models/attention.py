@@ -206,11 +206,34 @@ def attention_prefill(params, x, cache, cfg, *, window=None):
 
 
 # ------------------------------------------------------------ paged decode
-def paged_write(kv, k_new, v_new, block_tables, positions, active, *,
-                ring_pages=None):
-    """Scatter one token's K/V per sequence into the block pool.
+# The paged steps (models.transformer) carry every layer's pool stacked,
+# (L, N, bs, Hkv, hd), through their layer scan and pass `layer`, the
+# traced index of the layer at hand. Writes scatter only the new tokens at
+# (layer, block, offset) — in place, the pool being donated — and reads
+# gather only the blocks a table names, at the same index. No function here
+# slices a whole layer out of the stack.
+def _kv_values(kv, k, v):
+    """What a K/V write stores: the vectors themselves, or for an int8 pool
+    (with "k_scale"/"v_scale") their int8 values and per-vector scales."""
+    if "k_scale" in kv:
+        qk, sk, qv, sv = _quantize_pair(k, v)
+        return {"k": qk, "v": qv, "k_scale": sk, "v_scale": sv}
+    return {"k": k, "v": v}
 
-    kv: {"k","v"}: (N, bs, Hkv, hd); k_new/v_new: (B, Hkv, hd);
+
+def _scatter_kv(kv, layer, bids, offs, vals):
+    """Write ``vals`` (see :func:`_kv_values`) at (layer, bids, offs) of
+    the stacked pool; rows whose block id is out of range are dropped."""
+    return {n: kv[n].at[layer, bids, offs].set(vals[n], mode="drop")
+            for n in kv}
+
+
+def paged_write(kv, layer, k_new, v_new, block_tables, positions, active, *,
+                ring_pages=None):
+    """Scatter one token's K/V per sequence into layer ``layer`` of the
+    stacked block pool.
+
+    kv: {"k","v"}: (L, N, bs, Hkv, hd); k_new/v_new: (B, Hkv, hd);
     block_tables: (B, P); positions: (B,) absolute token position;
     active: (B,) bool — inactive rows are dropped (OOB block id).
     ring_pages: sliding-window layers write page (pos // bs) % ring_pages
@@ -218,7 +241,7 @@ def paged_write(kv, k_new, v_new, block_tables, positions, active, *,
     (with "k_scale"/"v_scale") quantizes on write, scattering the scales at
     the same (block, offset)."""
     with jax.named_scope("kv_write"):
-        N, bs = kv["k"].shape[0], kv["k"].shape[1]
+        N, bs = kv["k"].shape[1:3]
         B = positions.shape[0]
         pages = positions // bs
         if ring_pages is not None:
@@ -226,31 +249,22 @@ def paged_write(kv, k_new, v_new, block_tables, positions, active, *,
         bids = block_tables[jnp.arange(B), pages]
         bids = jnp.where(active, bids, N)       # OOB => mode="drop"
         offs = positions % bs
-        if "k_scale" in kv:
-            qk, sk, qv, sv = _quantize_pair(k_new, v_new)
-            return {
-                "k": kv["k"].at[bids, offs].set(qk, mode="drop"),
-                "v": kv["v"].at[bids, offs].set(qv, mode="drop"),
-                "k_scale": kv["k_scale"].at[bids, offs].set(sk, mode="drop"),
-                "v_scale": kv["v_scale"].at[bids, offs].set(sv, mode="drop"),
-            }
-        return {
-            "k": kv["k"].at[bids, offs].set(k_new, mode="drop"),
-            "v": kv["v"].at[bids, offs].set(v_new, mode="drop"),
-        }
+        return _scatter_kv(kv, layer, bids, offs,
+                           _kv_values(kv, k_new, v_new))
 
 
-def attention_decode_paged(params, x, kv, block_tables, positions, attn_lens,
-                           cfg, *, impl=None, window=None, ring_pages=None):
-    """One-token decode against a paged KV pool. x: (B,1,D); kv k/v pools
-    (N, bs, Hkv, hd); block_tables (B, P); positions (B,) absolute position of
-    the incoming token; attn_lens (B,) tokens to attend over INCLUDING the new
-    one (0 marks an inactive slot — its write is dropped and its output is
-    garbage the engine ignores). window/ring_pages switch sliding-window
-    layers to the ring layout (write modulo the ring, attend the last
-    `window` positions). ``impl`` ("kernel" | "ref") defaults to the
-    platform's choice (``repro.kernels.platform``). Returns (out (B,1,D),
-    new kv)."""
+def attention_decode_paged(params, x, kv, layer, block_tables, positions,
+                           attn_lens, cfg, *, impl=None, window=None,
+                           ring_pages=None):
+    """One-token decode against layer ``layer`` of the stacked paged pool.
+    x: (B,1,D); kv k/v pools (L, N, bs, Hkv, hd); block_tables (B, P);
+    positions (B,) absolute position of the incoming token; attn_lens (B,)
+    tokens to attend over INCLUDING the new one (0 marks an inactive slot —
+    its write is dropped and its output is garbage the engine ignores).
+    window/ring_pages switch sliding-window layers to the ring layout (write
+    modulo the ring, attend the last `window` positions). ``impl``
+    ("kernel" | "ref") defaults to the platform's choice
+    (``repro.kernels.platform``). Returns (out (B,1,D), new kv)."""
     from repro.kernels import platform
     from repro.kernels.paged_attention import paged_attention, paged_attention_ref
     h, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
@@ -259,66 +273,54 @@ def attention_decode_paged(params, x, kv, block_tables, positions, attn_lens,
     if cfg.rope_mode == "mrope":
         pos_b1 = jnp.broadcast_to(pos_b1[None], (3, B, 1))
     q, k_new, v_new = _project_qkv(params, x, pos_b1, cfg, window)
-    kv = paged_write(kv, k_new[:, 0], v_new[:, 0], block_tables, positions,
-                     attn_lens > 0, ring_pages=ring_pages)
+    kv = paged_write(kv, layer, k_new[:, 0], v_new[:, 0], block_tables,
+                     positions, attn_lens > 0, ring_pages=ring_pages)
     with jax.named_scope("attn"):
         scales = dict(k_scale=kv.get("k_scale"), v_scale=kv.get("v_scale"))
-        if (impl or platform.paged_attn_impl()) == "kernel":
-            out = paged_attention(q[:, 0], kv["k"], kv["v"], block_tables,
-                                  attn_lens, window=window,
-                                  positions=positions, ring_pages=ring_pages,
-                                  **scales)
-        else:
-            out = paged_attention_ref(q[:, 0], kv["k"], kv["v"], block_tables,
-                                      attn_lens, window=window,
-                                      positions=positions,
-                                      ring_pages=ring_pages, **scales)
+        attend = (paged_attention
+                  if (impl or platform.paged_attn_impl()) == "kernel"
+                  else paged_attention_ref)
+        out = attend(q[:, 0], kv["k"], kv["v"], block_tables, attn_lens,
+                     layer=layer, window=window, positions=positions,
+                     ring_pages=ring_pages, **scales)
     out = out.reshape(B, 1, h * hd)
     return jnp.einsum("bsk,kd->bsd", out, params["wo"]), kv
 
 
-def paged_write_multi(kv, k_new, v_new, block_tables, positions, valid, *,
-                      ring_pages=None):
-    """Scatter K draft tokens' K/V per sequence into the block pool.
+def paged_write_multi(kv, layer, k_new, v_new, block_tables, positions,
+                      valid, *, ring_pages=None):
+    """Scatter K draft tokens' K/V per sequence into layer ``layer`` of the
+    stacked block pool.
 
-    kv: {"k","v"}: (N, bs, Hkv, hd); k_new/v_new: (B, K, Hkv, hd);
+    kv: {"k","v"}: (L, N, bs, Hkv, hd); k_new/v_new: (B, K, Hkv, hd);
     block_tables: (B, P); positions: (B, K) absolute token positions;
     valid: (B, K) bool — invalid (rejected-horizon or inactive) writes are
     dropped (OOB block id) so pool contents stay canonical. ring_pages:
     sliding-window layers write page (pos // bs) % ring_pages. Int8 pools
     quantize on write as in :func:`paged_write`."""
     with jax.named_scope("kv_write"):
-        N, bs = kv["k"].shape[0], kv["k"].shape[1]
+        N, bs = kv["k"].shape[1:3]
         pages = positions // bs
         if ring_pages is not None:
             pages = pages % ring_pages
         bids = jnp.take_along_axis(block_tables, pages, axis=1)       # (B, K)
         bids = jnp.where(valid, bids, N)        # OOB => mode="drop"
         offs = positions % bs
-        if "k_scale" in kv:
-            qk, sk, qv, sv = _quantize_pair(k_new, v_new)
-            return {
-                "k": kv["k"].at[bids, offs].set(qk, mode="drop"),
-                "v": kv["v"].at[bids, offs].set(qv, mode="drop"),
-                "k_scale": kv["k_scale"].at[bids, offs].set(sk, mode="drop"),
-                "v_scale": kv["v_scale"].at[bids, offs].set(sv, mode="drop"),
-            }
-        return {
-            "k": kv["k"].at[bids, offs].set(k_new, mode="drop"),
-            "v": kv["v"].at[bids, offs].set(v_new, mode="drop"),
-        }
+        return _scatter_kv(kv, layer, bids, offs,
+                           _kv_values(kv, k_new, v_new))
 
 
-def attention_verify_paged(params, x, kv, block_tables, base, qlims, cfg, *,
-                           impl=None, window=None, ring_pages=None):
-    """Multi-query speculative verify against a paged KV pool. x: (B,K,D) —
-    K draft tokens per sequence, draft j at absolute position base[b] + j.
-    qlims (B,): number of draft positions whose K/V may be written this step
-    (0 marks an inactive slot); queries at or past qlims produce garbage the
-    engine discards, and their writes are dropped so rejected-horizon KV
-    never lands in the pool. window/ring_pages switch sliding-window layers
-    to the ring layout — the ring must be sized with `draft = K - 1` slack
-    (see state_providers.ring_pages). ``impl`` as in
+def attention_verify_paged(params, x, kv, layer, block_tables, base, qlims,
+                           cfg, *, impl=None, window=None, ring_pages=None):
+    """Multi-query speculative verify against layer ``layer`` of the stacked
+    paged pool. x: (B,K,D) — K draft tokens per sequence, draft j at
+    absolute position base[b] + j. qlims (B,): number of draft positions
+    whose K/V may be written this step (0 marks an inactive slot); queries
+    at or past qlims produce garbage the engine discards, and their writes
+    are dropped so rejected-horizon KV never lands in the pool.
+    window/ring_pages switch sliding-window layers to the ring layout — the
+    ring must be sized with `draft = K - 1` slack (see
+    state_providers.ring_pages). ``impl`` as in
     :func:`attention_decode_paged`. Returns (out (B,K,D), new kv)."""
     from repro.kernels import platform
     from repro.kernels.paged_attention import (paged_attention_verify,
@@ -331,35 +333,34 @@ def attention_verify_paged(params, x, kv, block_tables, base, qlims, cfg, *,
         pos_in = jnp.broadcast_to(pos_in[None], (3, B, K))
     q, k_new, v_new = _project_qkv(params, x, pos_in, cfg, window)
     write = jnp.arange(K)[None, :] < qlims[:, None]               # (B, K)
-    kv = paged_write_multi(kv, k_new, v_new, block_tables, positions, write,
-                           ring_pages=ring_pages)
+    kv = paged_write_multi(kv, layer, k_new, v_new, block_tables, positions,
+                           write, ring_pages=ring_pages)
     attn_lens = jnp.where(qlims > 0, base + K, 0)
     newest = attn_lens - 1
     with jax.named_scope("attn"):
         scales = dict(k_scale=kv.get("k_scale"), v_scale=kv.get("v_scale"))
-        if (impl or platform.paged_attn_impl()) == "kernel":
-            out = paged_attention_verify(
-                q, kv["k"], kv["v"], block_tables, attn_lens, window=window,
-                positions=newest, ring_pages=ring_pages, **scales)
-        else:
-            out = paged_attention_verify_ref(
-                q, kv["k"], kv["v"], block_tables, attn_lens, window=window,
-                positions=newest, ring_pages=ring_pages, **scales)
+        attend = (paged_attention_verify
+                  if (impl or platform.paged_attn_impl()) == "kernel"
+                  else paged_attention_verify_ref)
+        out = attend(q, kv["k"], kv["v"], block_tables, attn_lens,
+                     layer=layer, window=window, positions=newest,
+                     ring_pages=ring_pages, **scales)
     out = out.reshape(B, K, h * hd)
     return jnp.einsum("bsk,kd->bsd", out, params["wo"]), kv
 
 
-def attention_prefill_paged(params, x, kv, table_rows, starts, valids, cfg):
-    """Segment-masked packed prefill against the paged pool. x: (G,C,D) —
-    one prompt chunk per segment, segment g starting at absolute position
-    `starts[g]`, of which the first `valids[g]` tokens are real (the rest
-    padding; `valids[g] == 0` marks an all-padding segment whose writes are
-    dropped and whose output rows the caller ignores). Segments own disjoint
-    block tables (shared prefix blocks are read-only and not written here),
-    so the combined scatter plus per-segment gathers are race-free. Writes
-    each segment's chunk K/V into the pool, then attends causally over each
-    segment's own prefix gathered via its table row. Returns
-    (out (G,C,D), new kv)."""
+def attention_prefill_paged(params, x, kv, layer, table_rows, starts, valids,
+                            cfg):
+    """Segment-masked packed prefill against layer ``layer`` of the stacked
+    paged pool. x: (G,C,D) — one prompt chunk per segment, segment g
+    starting at absolute position `starts[g]`, of which the first
+    `valids[g]` tokens are real (the rest padding; `valids[g] == 0` marks an
+    all-padding segment whose writes are dropped and whose output rows the
+    caller ignores). Segments own disjoint block tables (shared prefix
+    blocks are read-only and not written here), so the combined scatter
+    plus per-segment gathers are race-free. Writes each segment's chunk K/V
+    into the pool, then attends causally over each segment's own prefix
+    gathered via its table row. Returns (out (G,C,D), new kv)."""
     h, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     G, C = x.shape[0], x.shape[1]
     pos = starts[:, None] + jnp.arange(C)[None, :]                # (G, C)
@@ -368,25 +369,12 @@ def attention_prefill_paged(params, x, kv, table_rows, starts, valids, cfg):
         positions = jnp.broadcast_to(positions[None], (3, G, C))
     q, k, v = _project_qkv(params, x, positions, cfg, None)
 
-    N, bs = kv["k"].shape[0], kv["k"].shape[1]
+    N, bs = kv["k"].shape[1:3]
     with jax.named_scope("kv_write"):
         valid = jnp.arange(C)[None, :] < valids[:, None]              # (G, C)
         bids = jnp.where(
             valid, jnp.take_along_axis(table_rows, pos // bs, axis=1), N)
-        offs = pos % bs
-        if "k_scale" in kv:
-            qk, sk, qv, sv = _quantize_pair(k, v)
-            kv = {
-                "k": kv["k"].at[bids, offs].set(qk, mode="drop"),
-                "v": kv["v"].at[bids, offs].set(qv, mode="drop"),
-                "k_scale": kv["k_scale"].at[bids, offs].set(sk, mode="drop"),
-                "v_scale": kv["v_scale"].at[bids, offs].set(sv, mode="drop"),
-            }
-        else:
-            kv = {
-                "k": kv["k"].at[bids, offs].set(k, mode="drop"),
-                "v": kv["v"].at[bids, offs].set(v, mode="drop"),
-            }
+        kv = _scatter_kv(kv, layer, bids, pos % bs, _kv_values(kv, k, v))
 
     # the gather-back below reads the (possibly quantized) pool contents, so
     # every query attends the same values the decode kernel will later see
@@ -396,14 +384,14 @@ def attention_prefill_paged(params, x, kv, table_rows, starts, valids, cfg):
         n_rep = h // hkv
         if "k_scale" in kv:
             kk = _repeat_kv(_gather_pool(kv["k"], kv["k_scale"], table_rows,
-                                         P * bs), n_rep)
+                                         P * bs, layer), n_rep)
             vv = _repeat_kv(_gather_pool(kv["v"], kv["v_scale"], table_rows,
-                                         P * bs), n_rep)
+                                         P * bs, layer), n_rep)
         else:
-            kk = _repeat_kv(kv["k"][table_rows].reshape(G, P * bs, hkv, hd),
-                            n_rep)
-            vv = _repeat_kv(kv["v"][table_rows].reshape(G, P * bs, hkv, hd),
-                            n_rep)
+            kk = _repeat_kv(kv["k"][layer, table_rows].reshape(
+                G, P * bs, hkv, hd), n_rep)
+            vv = _repeat_kv(kv["v"][layer, table_rows].reshape(
+                G, P * bs, hkv, hd), n_rep)
         scale = 1.0 / np.sqrt(hd)
         s = jnp.einsum("bqhd,bkhd->bhqk", q, kk).astype(jnp.float32) * scale
         mask = jnp.arange(P * bs)[None, None, :] <= pos[:, :, None]  # G,C,P*bs
@@ -413,12 +401,13 @@ def attention_prefill_paged(params, x, kv, table_rows, starts, valids, cfg):
     return jnp.einsum("bsk,kd->bsd", out, params["wo"]), kv
 
 
-def attention_prefill_ring(params, x, kv, table_rows, starts, valids, cfg,
-                           *, window, ring_pages):
-    """Segment-masked packed prefill against a RING-paged pool. x: (G,C,D) —
-    one chunk per segment starting at `starts[g]`, first `valids[g]` tokens
-    real. Each segment owns only `ring_pages` blocks; its position p lives
-    at `table_rows[g, (p // bs) % ring_pages]`, offset `p % bs`.
+def attention_prefill_ring(params, x, kv, layer, table_rows, starts, valids,
+                           cfg, *, window, ring_pages):
+    """Segment-masked packed prefill against layer ``layer`` of a stacked
+    RING-paged pool. x: (G,C,D) — one chunk per segment starting at
+    `starts[g]`, first `valids[g]` tokens real. Each segment owns only
+    `ring_pages` blocks; its position p lives at
+    `table_rows[g, (p // bs) % ring_pages]`, offset `p % bs`.
 
     Unlike the full-attention path (write, then gather everything back),
     the pre-chunk ring content is gathered BEFORE the chunk's writes: on
@@ -436,15 +425,15 @@ def attention_prefill_ring(params, x, kv, table_rows, starts, valids, cfg,
         positions = jnp.broadcast_to(positions[None], (3, G, C))
     q, k, v = _project_qkv(params, x, positions, cfg, window)
     quant = "k_scale" in kv
+    vals = _kv_values(kv, k, v)
     if quant:
         # chunk keys are attended from registers (never re-read from the
         # pool), so round-trip them explicitly for parity with the decode
         # steps that WILL read them back quantized
-        qk, sk, qv, sv = _quantize_pair(k, v)
-        k = dequantize_kv(qk, sk).astype(k.dtype)
-        v = dequantize_kv(qv, sv).astype(v.dtype)
+        k = dequantize_kv(vals["k"], vals["k_scale"]).astype(k.dtype)
+        v = dequantize_kv(vals["v"], vals["v_scale"]).astype(v.dtype)
 
-    N, bs = kv["k"].shape[0], kv["k"].shape[1]
+    N, bs = kv["k"].shape[1:3]
     R = ring_pages
 
     # 1) gather each segment's ring as of starts-1 (before this chunk's
@@ -452,11 +441,13 @@ def attention_prefill_ring(params, x, kv, table_rows, starts, valids, cfg,
     with jax.named_scope("attn"):
         ring_rows = table_rows[:, :R]                                 # (G, R)
         if quant:
-            old_k = _gather_pool(kv["k"], kv["k_scale"], ring_rows, R * bs)
-            old_v = _gather_pool(kv["v"], kv["v_scale"], ring_rows, R * bs)
+            old_k = _gather_pool(kv["k"], kv["k_scale"], ring_rows, R * bs,
+                                 layer)
+            old_v = _gather_pool(kv["v"], kv["v_scale"], ring_rows, R * bs,
+                                 layer)
         else:
-            old_k = kv["k"][ring_rows].reshape(G, R * bs, hkv, hd)
-            old_v = kv["v"][ring_rows].reshape(G, R * bs, hkv, hd)
+            old_k = kv["k"][layer, ring_rows].reshape(G, R * bs, hkv, hd)
+            old_v = kv["v"][layer, ring_rows].reshape(G, R * bs, hkv, hd)
     old_pos = ring_key_positions(starts - 1, R, bs)               # (G, R*bs)
     # entries the pre-chunk ring never held: pages < 0 entirely, and the
     # current page's offsets past (start-1) % bs (previous-lap leftovers,
@@ -475,19 +466,7 @@ def attention_prefill_ring(params, x, kv, table_rows, starts, valids, cfg,
                  & (pos > last_valid - R * bs))
         bids = jnp.where(
             write, jnp.take_along_axis(table_rows, (pos // bs) % R, axis=1), N)
-        offs = pos % bs
-        if quant:
-            kv = {
-                "k": kv["k"].at[bids, offs].set(qk, mode="drop"),
-                "v": kv["v"].at[bids, offs].set(qv, mode="drop"),
-                "k_scale": kv["k_scale"].at[bids, offs].set(sk, mode="drop"),
-                "v_scale": kv["v_scale"].at[bids, offs].set(sv, mode="drop"),
-            }
-        else:
-            kv = {
-                "k": kv["k"].at[bids, offs].set(k, mode="drop"),
-                "v": kv["v"].at[bids, offs].set(v, mode="drop"),
-            }
+        kv = _scatter_kv(kv, layer, bids, pos % bs, vals)
 
     # 3) attend: keys = each segment's pre-chunk ring ∪ its own chunk
     with jax.named_scope("attn"):

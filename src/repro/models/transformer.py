@@ -353,11 +353,19 @@ def init_paged_state(cfg: ModelConfig, num_blocks: int, block_size: int,
       rwkv / mamba layers — per-slot recurrent slabs (n_sb, max_slots, ...);
         no block accounting at all.
 
+    The paged steps below carry this whole stacked state through their
+    layer scan and address superblock i's storage by the loop index: writes
+    scatter only the new tokens at [i, block, offset], reads gather only
+    the blocks a table names at [i, ...] (the Pallas kernel takes the stack
+    and the index), and recurrent slabs are read and written back at [i].
+    With the state donated, XLA updates it in place. No hot path may slice
+    a whole layer out of it: such a slice, or a scan over the state as
+    ``xs``, copies the pool on every step.
+
     `max_slots` is required whenever the config has recurrent layers.
     `kv_quant` (KVQuantConfig) switches the paged pools to int8 values with
     per-vector f32 scales; the dict structure carries the mode so the jitted
     steps dispatch statically."""
-    kinds = _layer_kinds(cfg)
     skinds = SP.state_kinds(cfg)
     if any(k in ("rwkv", "mamba") for k in skinds) and max_slots is None:
         raise ValueError("recurrent layers need max_slots for their state slab")
@@ -368,6 +376,29 @@ def init_paged_state(cfg: ModelConfig, num_blocks: int, block_size: int,
                                  kv_quant=kv_quant)
     one = {f"l{i}": p.init_layer_state() for i, p in enumerate(providers)}
     return jax.tree.map(lambda a: jnp.broadcast_to(a[None], (n_sb,) + a.shape), one)
+
+
+def _scan_pool(body, x, params, pool):
+    """Scan the superblocks with (x, pool) as the carry (see
+    :func:`init_paged_state`). ``body(x, pool, sb_params, i)`` gets the
+    whole stacked pool and the superblock index ``i`` and returns
+    ``(x, pool, y)``, the pool changed only where it wrote. Returns
+    ``(x, pool, ys)``, ys stacked over superblocks."""
+    n_sb = jax.tree.leaves(params["blocks"])[0].shape[0]
+
+    def step(carry, xs):
+        x, pool, y = body(*carry, *xs)
+        return (x, pool), y
+
+    (x, pool), ys = jax.lax.scan(
+        step, (x, pool), (params["blocks"], jnp.arange(n_sb, dtype=jnp.int32)))
+    return x, pool, ys
+
+
+def _slab_at(slab, i):
+    """Superblock i's rows of a stacked recurrent slab (small: per-slot
+    state, not a pool)."""
+    return jax.tree.map(lambda a: a[i], slab)
 
 
 def _attn_block(kind, p, lp, h_in, cfg, attn_out):
@@ -385,52 +416,54 @@ def paged_decode_step(cfg: ModelConfig, params, pool, inputs, block_tables,
     layer to its state kind. inputs: {"token": (B,)}; block_tables: (B, P);
     positions: (B,) absolute position of each incoming token; attn_lens:
     (B,) tokens to attend over including the new one (0 = inactive slot).
-    Recurrent slabs are per-slot (B == max_slots) and their updates are
-    masked for inactive slots, so slots mid-prefill are never corrupted by
-    the batched decode. ``draft`` must match the engine's speculative K-1
-    (0 when speculation is off) so ring layers use the same enlarged ring
-    as the verify step. ``impl`` picks the paged attention path (None: the
-    platform's, see ``repro.kernels.platform``). Returns (logits (B,V), new
-    pool)."""
+    The layer scan carries ``pool`` and addresses superblock i by index
+    (:func:`init_paged_state`): each paged layer scatters one token per
+    slot in place and its attention reads the stack at i. Recurrent slabs
+    are per-slot (B == max_slots) and their updates are masked for inactive
+    slots, so slots mid-prefill are never corrupted by the batched decode.
+    ``draft`` must match the engine's speculative K-1 (0 when speculation
+    is off) so ring layers use the same enlarged ring as the verify step.
+    ``impl`` picks the paged attention path (None: the platform's, see
+    ``repro.kernels.platform``). Returns (logits (B,V), new pool)."""
     x = _embed_tokens(cfg, params, inputs["token"][:, None])
     kinds = _layer_kinds(cfg)
     skinds = SP.state_kinds(cfg)
     shared = params.get("shared_attn")
     active = attn_lens > 0
 
-    def scan_body(x, sb):
-        sb_params, sb_pool = sb
-        new_pool = {}
-        for i, (kind, skind) in enumerate(zip(kinds, skinds)):
-            lp = sb_params[f"l{i}"]
-            st = sb_pool[f"l{i}"]
+    def body(x, pool, sb_params, i):
+        pool = dict(pool)
+        for j, (kind, skind) in enumerate(zip(kinds, skinds)):
+            name = f"l{j}"
+            lp = sb_params[name]
+            st = pool[name]
             if skind in ("full", "ring"):
                 p = shared if kind == "shared_attn" else lp
                 window = cfg.window_size if skind == "ring" else None
-                rp = (SP.ring_pages(window, st["k"].shape[1], draft=draft)
+                rp = (SP.ring_pages(window, st["k"].shape[2], draft=draft)
                       if skind == "ring" else None)
                 h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
-                y, kv = A.attention_decode_paged(
-                    p["attn"], h, st, block_tables, positions, attn_lens,
+                y, pool[name] = A.attention_decode_paged(
+                    p["attn"], h, st, i, block_tables, positions, attn_lens,
                     cfg, impl=impl, window=window, ring_pages=rp)
                 x = _attn_block(kind, p, lp, x, cfg, y)
-                new_pool[f"l{i}"] = kv
             else:
-                x1, new_st = _apply_layer_decode(kind, lp, st, x,
-                                                 jnp.int32(0), cfg, shared)
-                new_st = jax.tree.map(
+                old = _slab_at(st, i)
+                x, new = _apply_layer_decode(kind, lp, old, x, jnp.int32(0),
+                                             cfg, shared)
+                new = jax.tree.map(
                     lambda n, o: jnp.where(
                         active.reshape((-1,) + (1,) * (n.ndim - 1)), n, o),
-                    new_st, st)
-                x = x1
-                new_pool[f"l{i}"] = new_st
-        return x, new_pool
+                    new, old)
+                pool[name] = jax.tree.map(lambda a, n: a.at[i].set(n), st,
+                                          new)
+        return x, pool, None
 
-    x, new_pools = jax.lax.scan(scan_body, x, (params["blocks"], pool))
+    x, pool, _ = _scan_pool(body, x, params, pool)
     with jax.named_scope("head"):
         x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
         lg = logits(cfg, params, x)[:, 0]
-    return lg, new_pools
+    return lg, pool
 
 
 def _recurrent_verify_layer(kind, lp, slab, x, cfg, shared):
@@ -455,59 +488,62 @@ def paged_verify_step(cfg: ModelConfig, params, pool, tokens, block_tables,
     """Multi-query speculative verify for a continuous batch of slots.
     tokens: (B, K) — K draft tokens per slot, draft j at absolute position
     `base[b] + j`; qlims: (B,) number of draft positions that may commit
-    K/V this step (0 = inactive slot). Paged layers write the first
-    qlims[b] drafts' K/V (write-then-attend) and attend causally among the
-    draft positions; recurrent layers scan the K tokens capturing per-step
-    checkpoint states for exact rollback. Returns (logits (B, K, V),
-    new pool) where recurrent entries hold stacked checkpoints
-    (n_sb, K, max_slots, ...) — the caller selects the accepted checkpoint
-    via state_providers.select_checkpoint."""
+    K/V this step (0 = inactive slot). The layer scan carries ``pool`` and
+    addresses superblock i by index (:func:`init_paged_state`). Paged
+    layers write the first qlims[b] drafts' K/V in place (write-then-attend)
+    and attend causally among the draft positions; recurrent layers read
+    their slab at i and scan the K tokens capturing per-step checkpoint
+    states for exact rollback. Returns (logits (B, K, V), new pool) where
+    recurrent entries hold stacked checkpoints (n_sb, K, max_slots, ...) —
+    the caller selects the accepted checkpoint via
+    state_providers.select_checkpoint."""
     x = _embed_tokens(cfg, params, tokens)                        # (B, K, D)
     K = tokens.shape[1]
     kinds = _layer_kinds(cfg)
     skinds = SP.state_kinds(cfg)
     shared = params.get("shared_attn")
 
-    def scan_body(x, sb):
-        sb_params, sb_pool = sb
-        new_pool = {}
-        for i, (kind, skind) in enumerate(zip(kinds, skinds)):
-            lp = sb_params[f"l{i}"]
-            st = sb_pool[f"l{i}"]
+    def body(x, pool, sb_params, i):
+        pool, cps = dict(pool), {}
+        for j, (kind, skind) in enumerate(zip(kinds, skinds)):
+            name = f"l{j}"
+            lp = sb_params[name]
+            st = pool[name]
             if skind in ("full", "ring"):
                 p = shared if kind == "shared_attn" else lp
                 window = cfg.window_size if skind == "ring" else None
-                rp = (SP.ring_pages(window, st["k"].shape[1], draft=K - 1)
+                rp = (SP.ring_pages(window, st["k"].shape[2], draft=K - 1)
                       if skind == "ring" else None)
                 h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
-                y, kv = A.attention_verify_paged(
-                    p["attn"], h, st, block_tables, base, qlims, cfg,
+                y, pool[name] = A.attention_verify_paged(
+                    p["attn"], h, st, i, block_tables, base, qlims, cfg,
                     impl=impl, window=window, ring_pages=rp)
                 x = _attn_block(kind, p, lp, x, cfg, y)
-                new_pool[f"l{i}"] = kv
             else:
-                y, cps = _recurrent_verify_layer(kind, lp, st, x, cfg, shared)
-                x = y
-                new_pool[f"l{i}"] = cps
-        return x, new_pool
+                x, cps[name] = _recurrent_verify_layer(
+                    kind, lp, _slab_at(st, i), x, cfg, shared)
+        return x, pool, cps
 
-    x, new_pools = jax.lax.scan(scan_body, x, (params["blocks"], pool))
+    x, pool, cps = _scan_pool(body, x, params, pool)
     with jax.named_scope("head"):
         x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
         lg = logits(cfg, params, x)                               # (B, K, V)
-    return lg, new_pools
+    return lg, {**pool, **cps}
 
 
-def _recurrent_prefill_layer(kind, lp, slab, x, valids, slots, cfg, shared):
+def _recurrent_prefill_layer(kind, lp, slab, i, x, valids, slots, cfg,
+                             shared):
     """Packed chunked prefill through a recurrent layer: a token scan of the
     decode path (recurrent state has no one-shot prefill), with per-segment
     state updates masked past `valids[g]` so each slab row ends at exactly
-    its last real token. slab leaves: (max_slots, ...); x: (G, C, D);
-    slots: (G,) slab row per segment — `slots[g] >= max_slots` marks a
-    padded segment (its gather clamps to an arbitrary row and its write-back
-    is dropped). Returns (y (G,C,D), new slab)."""
-    max_slots = jax.tree.leaves(slab)[0].shape[0]
-    st0 = jax.tree.map(lambda a: a[jnp.minimum(slots, max_slots - 1)], slab)
+    its last real token. slab leaves: stacked (n_sb, max_slots, ...), read
+    and written at superblock ``i``; x: (G, C, D); slots: (G,) slab row per
+    segment — `slots[g] >= max_slots` marks a padded segment (its gather
+    clamps to an arbitrary row and its write-back is dropped). Returns
+    (y (G,C,D), new slab)."""
+    max_slots = jax.tree.leaves(slab)[0].shape[1]
+    st0 = jax.tree.map(lambda a: a[i, jnp.minimum(slots, max_slots - 1)],
+                       slab)
 
     def body(st, t):
         xt = jax.lax.dynamic_slice_in_dim(x, t, 1, axis=1)        # (G,1,D)
@@ -520,7 +556,7 @@ def _recurrent_prefill_layer(kind, lp, slab, x, valids, slots, cfg, shared):
 
     stf, ys = jax.lax.scan(body, st0, jnp.arange(x.shape[1]))
     y = ys.swapaxes(0, 1)                                         # (G, C, D)
-    slab = jax.tree.map(lambda a, s: a.at[slots].set(s, mode="drop"),
+    slab = jax.tree.map(lambda a, s: a.at[i, slots].set(s, mode="drop"),
                         slab, stf)
     return y, slab
 
@@ -536,47 +572,47 @@ def paged_prefill_packed(cfg: ModelConfig, params, pool, tokens, tables,
     its paged writes drop (valids[g] == 0) and its recurrent-slab write-back
     drops, so padded segments never touch sequence state. Segments' block
     tables are disjoint where written, so packing G chunks is bit-identical
-    to G separate calls. Returns (logits (G, V) of each segment's last
-    valid token, new pool)."""
+    to G separate calls. The layer scan carries ``pool`` and addresses
+    superblock i by index (:func:`init_paged_state`): chunk K/V is
+    scattered in place and attention gathers the segments' blocks at i.
+    Returns (logits (G, V) of each segment's last valid token, new pool)."""
     x = _embed_tokens(cfg, params, tokens)
     kinds = _layer_kinds(cfg)
     skinds = SP.state_kinds(cfg)
     shared = params.get("shared_attn")
     rows = jnp.take(tables, jnp.minimum(slots, tables.shape[0] - 1), axis=0)
 
-    def scan_body(x, sb):
-        sb_params, sb_pool = sb
-        new_pool = {}
-        for i, (kind, skind) in enumerate(zip(kinds, skinds)):
-            lp = sb_params[f"l{i}"]
-            st = sb_pool[f"l{i}"]
+    def body(x, pool, sb_params, i):
+        pool = dict(pool)
+        for j, (kind, skind) in enumerate(zip(kinds, skinds)):
+            name = f"l{j}"
+            lp = sb_params[name]
+            st = pool[name]
             if skind in ("full", "ring"):
                 p = shared if kind == "shared_attn" else lp
                 h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
                 if skind == "ring":
-                    rp = SP.ring_pages(cfg.window_size, st["k"].shape[1],
+                    rp = SP.ring_pages(cfg.window_size, st["k"].shape[2],
                                        draft=draft)
-                    y, kv = A.attention_prefill_ring(
-                        p["attn"], h, st, rows, starts, valids, cfg,
+                    y, pool[name] = A.attention_prefill_ring(
+                        p["attn"], h, st, i, rows, starts, valids, cfg,
                         window=cfg.window_size, ring_pages=rp)
                 else:
-                    y, kv = A.attention_prefill_paged(
-                        p["attn"], h, st, rows, starts, valids, cfg)
+                    y, pool[name] = A.attention_prefill_paged(
+                        p["attn"], h, st, i, rows, starts, valids, cfg)
                 x = _attn_block(kind, p, lp, x, cfg, y)
-                new_pool[f"l{i}"] = kv
             else:
-                x, new_st = _recurrent_prefill_layer(
-                    kind, lp, st, x, valids, slots, cfg, shared)
-                new_pool[f"l{i}"] = new_st
-        return x, new_pool
+                x, pool[name] = _recurrent_prefill_layer(
+                    kind, lp, st, i, x, valids, slots, cfg, shared)
+        return x, pool, None
 
-    x, new_pools = jax.lax.scan(scan_body, x, (params["blocks"], pool))
+    x, pool, _ = _scan_pool(body, x, params, pool)
     with jax.named_scope("head"):
         x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
         idx = jnp.maximum(valids - 1, 0)                          # (G,)
         last = jnp.take_along_axis(x, idx[:, None, None], axis=1)  # (G,1,D)
         lg = logits(cfg, params, last)[:, 0]
-    return lg, new_pools
+    return lg, pool
 
 
 def paged_prefill_step(cfg: ModelConfig, params, pool, tokens, table_row,

@@ -17,6 +17,16 @@ pytestmark = pytest.mark.serving
 NEG_INF = -1e30
 
 
+def _stack(pool, layer, n_layers=4):
+    """``pool`` as layer ``layer`` of an (n_layers, ...) stack whose other
+    layers are poison: NaN for float leaves, 127 for int8 values (whose NaN
+    scales poison them). A read outside the indexed layer turns the output
+    NaN."""
+    fill = 127 if pool.dtype == jnp.int8 else jnp.nan
+    stack = jnp.full((n_layers,) + pool.shape, fill, pool.dtype)
+    return stack.at[layer].set(pool)
+
+
 def _random_case(key, B, H, Hkv, hd, N, bs, P, dtype, lens):
     k1, k2, k3, k4 = jax.random.split(key, 4)
     q = jax.random.normal(k1, (B, H, hd), jnp.float32).astype(dtype)
@@ -29,17 +39,21 @@ def _random_case(key, B, H, Hkv, hd, N, bs, P, dtype, lens):
 
 
 class TestPagedAttentionSweep:
+    @pytest.mark.parametrize("layer", [None, 2], ids=["pool", "stack"])
     @pytest.mark.parametrize("H,Hkv,hd", [(4, 4, 32), (4, 2, 64), (8, 1, 32)])
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-    def test_kernel_vs_ref(self, H, Hkv, hd, dtype):
+    def test_kernel_vs_ref(self, H, Hkv, hd, dtype, layer):
+        """One layer's pool, or the layers' stack read at ``layer``."""
         B, N, bs, P = 3, 24, 8, 4
         # lengths cross page boundaries, fill exactly, and include a mid-page
         lens = [1, bs * P, bs + 3]
         q, kp, vp, tables, lens = _random_case(
             jax.random.PRNGKey(H * 100 + hd), B, H, Hkv, hd, N, bs, P,
             dtype, lens)
-        out = paged_attention(q, kp, vp, tables, lens)
-        ref = paged_attention_ref(q, kp, vp, tables, lens)
+        if layer is not None:
+            kp, vp = _stack(kp, layer), _stack(vp, layer)
+        out = paged_attention(q, kp, vp, tables, lens, layer=layer)
+        ref = paged_attention_ref(q, kp, vp, tables, lens, layer=layer)
         tol = 2e-5 if dtype == jnp.float32 else 0.08
         np.testing.assert_allclose(np.asarray(out, np.float32),
                                    np.asarray(ref, np.float32), atol=tol)
@@ -203,3 +217,72 @@ class TestQuantizedPagedAttention:
                     paged_attention_ref(q, qk, qv, tables, lens, **scales)):
             assert bool(jnp.all(out[1] == 0))
             assert bool(jnp.all(jnp.isfinite(out)))
+
+
+# ----------------------------------------------- the layers' stacked pool
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("mode", ["full", "ring", "verify", "ring_verify"])
+def test_stacked_pool_kernel_vs_ref(mode, quant):
+    """The layer scan hands the kernel every layer's pool stacked and the
+    layer's index (a traced scalar). Read at layer 2 of a stack whose other
+    layers are NaN, kernel and reference must match the reference on that
+    layer's pool alone, and the reference bit for bit."""
+    cases = TestQuantizedPagedAttention()
+    k = 4 if mode.endswith("verify") else None
+    if mode.startswith("ring"):
+        q, kp, vp, tables, lens, kw = cases._ring_case(k)
+    else:
+        q, kp, vp, tables, lens = cases._full_case(k)
+        kw = {}
+    op, rf = ((paged_attention_verify, paged_attention_verify_ref)
+              if k else (paged_attention, paged_attention_ref))
+    if quant:
+        kp, vp, scales = _quantize_pools(kp, vp)
+    else:
+        q, kp, vp = (a.astype(jnp.bfloat16) for a in (q, kp, vp))
+        scales = {}
+    layer = jnp.int32(2)
+    stacked = dict(layer=layer, **{n: _stack(a, 2) for n, a in scales.items()})
+    ks, vs = _stack(kp, 2), _stack(vp, 2)
+    alone = rf(q, kp, vp, tables, lens, **scales, **kw)
+    ref = rf(q, ks, vs, tables, lens, **stacked, **kw)
+    out = op(q, ks, vs, tables, lens, **stacked, **kw)
+    np.testing.assert_array_equal(np.asarray(ref, np.float32),
+                                  np.asarray(alone, np.float32))
+    assert bool(jnp.all(jnp.isfinite(out)))
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(alone, np.float32),
+                               atol=2e-5 if quant else 0.08)
+
+
+@pytest.mark.parametrize("mode", ["full", "ring", "verify", "ring_verify"])
+def test_pages_span_grid_steps(mode):
+    """Tables longer than one grid step's pages (PAGES_PER_STEP) and not a
+    multiple of it: lengths end inside the first step, exactly at its end,
+    one token into the second, and at the table's end (the last step's
+    pages past the table repeat its last entry and are skipped)."""
+    from repro.kernels.paged_attention.kernel import PAGES_PER_STEP
+    B, H, Hkv, hd, bs = 4, 4, 2, 32, 4
+    P = PAGES_PER_STEP + 3
+    verify, ring = mode.endswith("verify"), mode.startswith("ring")
+    K = 3 if verify else 1
+    lens = [K + 2, PAGES_PER_STEP * bs, PAGES_PER_STEP * bs + 1, P * bs]
+    q, kp, vp, tables, lens = _random_case(
+        jax.random.PRNGKey(11), B, H, Hkv, hd, B * P + 2, bs, P,
+        jnp.float32, lens)
+    kw = {}
+    if ring:
+        # a window whose ring is the whole table, entered past its first lap
+        window = (P - 1) * bs - (K - 1)
+        assert SP.ring_pages(window, bs, draft=K - 1) == P
+        lens = lens + 2 * P * bs
+        kw = dict(window=window, positions=lens - 1, ring_pages=P)
+    if verify:
+        q = jax.random.normal(jax.random.PRNGKey(12), (B, K, H, hd))
+        op, rf = paged_attention_verify, paged_attention_verify_ref
+    else:
+        op, rf = paged_attention, paged_attention_ref
+    ks, vs = _stack(kp, 1, 3), _stack(vp, 1, 3)
+    out = op(q, ks, vs, tables, lens, layer=jnp.int32(1), **kw)
+    ref = rf(q, kp, vp, tables, lens, **kw)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)

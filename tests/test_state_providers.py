@@ -17,7 +17,8 @@ from repro.kernels.paged_attention import paged_attention, paged_attention_ref
 from repro.models import state_providers as SP
 from repro.models import transformer as T
 from repro.serving import serve
-from repro.serving.engine import BlockPool, Engine, EngineConfig
+from repro.serving.engine import (BlockPool, Engine, EngineConfig,
+                                  KVQuantConfig)
 
 pytestmark = [pytest.mark.serving, pytest.mark.state_providers]
 
@@ -409,6 +410,112 @@ class TestEngineAllFamilies:
 
 
 # ------------------------------------------------------- request validation
+# ------------------------------------------- steps write only live tokens
+class TestStepsWriteOnlyLiveTokens:
+    """The paged steps carry the stacked pool through their layer scan and
+    write it in place. After a decode, a packed prefill and a verify step,
+    every pool entry that no live token wrote is bit-identical to before,
+    and the written entries changed in every superblock (so no layer's
+    write lands at another's index). Recurrent slab rows of slots a step
+    does not serve are untouched, and those it serves changed in every
+    superblock."""
+
+    BS, N, P, B, K = 4, 16, 4, 4, 3
+
+    def _setup(self, family, quant, fam_params):
+        cfg, params = fam_params(family)
+        pool = T.init_paged_state(cfg, self.N, self.BS, max_slots=self.B,
+                                  kv_quant=KVQuantConfig() if quant else None)
+        keys = iter(jax.random.split(jax.random.PRNGKey(1), 64))
+
+        def noise(a):       # every entry distinct from what a step writes
+            if a.dtype == jnp.int8:
+                return jax.random.randint(next(keys), a.shape, -127, 128,
+                                          jnp.int32).astype(jnp.int8)
+            return jax.random.uniform(next(keys), a.shape, a.dtype, 0.5, 2.0)
+
+        pool = {n: jax.tree.map(noise, st) for n, st in pool.items()}
+        tables = jnp.arange(self.B * self.P, dtype=jnp.int32).reshape(
+            self.B, self.P)
+        return cfg, params, pool, tables
+
+    def _written(self, cfg, skind, tables, writes):
+        """(N, bs) mask of the entries the (slot, position) pairs write."""
+        mask = np.zeros((self.N, self.BS), bool)
+        R = (SP.ring_pages(cfg.window_size, self.BS, draft=self.K - 1)
+             if skind == "ring" else None)
+        for b, pos in writes:
+            page = pos // self.BS if R is None else (pos // self.BS) % R
+            mask[int(tables[b, page]), pos % self.BS] = True
+        return mask
+
+    def _check(self, cfg, before, after, tables, writes, served):
+        skinds = {f"l{j}": k for j, k in enumerate(SP.state_kinds(cfg))}
+        for name in before:
+            skind = skinds[name]
+            for leaf, old, new in zip(before[name], before[name].values(),
+                                      after[name].values()):
+                old, new = np.asarray(old), np.asarray(new)
+                if skind in ("full", "ring"):
+                    mask = self._written(cfg, skind, tables, writes)
+                    np.testing.assert_array_equal(new[:, ~mask], old[:, ~mask],
+                                                  err_msg=f"{name}/{leaf}")
+                    for i in range(old.shape[0]):
+                        assert not np.array_equal(new[i][mask], old[i][mask]), (
+                            f"{name}/{leaf}: superblock {i} not written")
+                else:
+                    idle = [b for b in range(self.B) if b not in served]
+                    np.testing.assert_array_equal(new[:, idle], old[:, idle],
+                                                  err_msg=f"{name}/{leaf}")
+                    for i in range(old.shape[0]):
+                        for b in served:
+                            assert not np.array_equal(new[i, b], old[i, b]), (
+                                f"{name}/{leaf}: superblock {i} slot {b}")
+
+    @pytest.mark.parametrize("family,quant", [(f, False) for f in FAMILIES]
+                             + [("full", True), ("sliding", True)])
+    def test_decode_prefill_verify(self, family, quant, fam_params):
+        cfg, params, pool, tables = self._setup(family, quant, fam_params)
+        copy = lambda tree: jax.tree.map(jnp.array, tree)
+
+        # decode: slot 1 inactive
+        pos = jnp.asarray([5, 0, 9, 3], jnp.int32)
+        active = np.array([True, False, True, True])
+        lens = jnp.where(active, pos + 1, 0)
+        _, after = T.paged_decode_step(
+            cfg, params, copy(pool), {"token": jnp.arange(self.B) % 50},
+            tables, pos, lens, draft=self.K - 1)
+        self._check(cfg, pool, after, tables,
+                    [(b, int(pos[b])) for b in range(self.B) if active[b]],
+                    served={0, 2, 3})
+
+        # packed prefill: slot 2 from position 4, 6 real tokens; a padded
+        # segment (slot == max_slots, nothing valid)
+        starts = jnp.asarray([4, 0], jnp.int32)
+        valids = jnp.asarray([6, 0], jnp.int32)
+        slots = jnp.asarray([2, self.B], jnp.int32)
+        toks = jnp.arange(16, dtype=jnp.int32).reshape(2, 8) % 50
+        _, after = T.paged_prefill_packed(
+            cfg, params, copy(pool), toks, tables, starts, valids, slots,
+            draft=self.K - 1)
+        self._check(cfg, pool, after, tables,
+                    [(2, p) for p in range(4, 10)], served={2})
+
+        # verify: K drafts per slot, qlims caps the writes; slot 1 inactive
+        base = jnp.asarray([5, 0, 9, 3], jnp.int32)
+        qlims = jnp.asarray([3, 0, 2, 1], jnp.int32)
+        toks = (jnp.arange(self.B * self.K, dtype=jnp.int32).reshape(
+            self.B, self.K) % 50)
+        _, after = T.paged_verify_step(cfg, params, copy(pool), toks, tables,
+                                       base, qlims)
+        paged = {f"l{j}": after[f"l{j}"]
+                 for j, k in enumerate(SP.state_kinds(cfg))
+                 if k in ("full", "ring")}
+        self._check(cfg, {n: pool[n] for n in paged}, paged, tables,
+                    [(b, int(base[b]) + j) for b in range(self.B)
+                     for j in range(int(qlims[b]))], served=set())
+
+
 class TestAddRequestValidation:
     def test_oversized_total_raises_with_numbers(self, fam_params):
         cfg, params = fam_params("full")

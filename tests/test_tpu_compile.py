@@ -2,8 +2,10 @@
 
 The paged-attention kernel in its four modes (full / ring / verify /
 ring-verify, bf16 and int8-scale pools) at phi4-mini's widths, the
-engine's whole phi4-mini decode step at chip_smoke.py's pool size, and
-chip_smoke.py's four-chip trainer (stablelm-3b at full width, its depth
+engine's whole phi4-mini decode step at chip_smoke.py's pool size and at
+the offline benchmark cell's, the engine's decode and packed prefill
+writing that pool in place (no instruction copies or slices a layer of
+it), and chip_smoke.py's four-chip trainer (stablelm-3b at full width, its depth
 cut as the smoke cuts it) on a 2x2 mesh, sharded and in paper mode. Mosaic
 refuses here what interpret mode accepts (unaligned slices, too much VMEM),
 and XLA refuses a program that does not fit the chip. Nothing runs.
@@ -15,6 +17,7 @@ tests steer ``repro.kernels.platform.on_tpu``.
 """
 import dataclasses
 import functools
+import re
 import sys
 
 import jax
@@ -29,6 +32,12 @@ from repro.kernels import platform
 H, HKV, HD, BS, B = 24, 8, 128, 16, 8        # phi4-mini widths, smoke batch
 N, P, K, WINDOW = 1024, 40, 4, 512           # pool, table, drafts, ring
 HBM = 16 * 2**30                             # one v5e chip
+GiB = 2**30
+# the phi4mini.offline benchmark cell's engine (perfbench/traffic/offline.json)
+OFFLINE = dict(block_size=16, num_blocks=1536, max_slots=32,
+               max_blocks_per_seq=128, prefill_chunk=512, prefills_per_step=2)
+INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* ([\w-]+)\(",
+                         re.M)
 
 
 @pytest.fixture(scope="module")
@@ -113,29 +122,85 @@ def test_paged_kernel_compiles(one_chip, mode, quant):
     assert "tpu_custom_call" in text
 
 
-def test_phi4_decode_step_fits_one_chip(one_chip):
-    chip_smoke = _chip_smoke()
+def _engine_config(shapes):
+    from repro.serving.engine import EngineConfig
+    if shapes == "smoke":
+        return _chip_smoke().smoke_engine_config()
+    return EngineConfig(**OFFLINE)
+
+
+@pytest.fixture(scope="module")
+def engine_program(one_chip):
+    """``get(shapes, name)``: the engine's phi4-mini ``decode`` or packed
+    ``prefill`` (2 segments of a full chunk) compiled for one chip, at
+    chip_smoke.py's engine shapes ("smoke") or the offline cell's
+    ("offline"); each compiled once per module."""
     from repro.models import transformer as T
     from repro.serving.engine.engine import _build_step_fns
     cfg = get_config("phi4-mini-3.8b")
-    ecfg = chip_smoke.smoke_engine_config()
-    decode = _build_step_fns(cfg, ecfg, None)[0]
     place = lambda tree: jax.tree.map(
         lambda a: _spec(a.shape, a.dtype, one_chip), tree)
-    params = place(jax.eval_shape(lambda k: T.init_params(cfg, k),
-                                  jax.random.PRNGKey(0)))
-    pool = place(jax.eval_shape(lambda: T.init_paged_state(
-        cfg, ecfg.num_blocks, ecfg.block_size, max_slots=ecfg.max_slots)))
-    slots = ecfg.max_slots
-    compiled = decode.lower(
-        params, pool, _spec((slots,), jnp.int32, one_chip),
-        _spec((slots, ecfg.max_blocks_per_seq), jnp.int32, one_chip),
-        _spec((slots,), jnp.int32, one_chip),
-        _spec((slots,), jnp.bool_, one_chip)).compile()
+    i32 = lambda *shape: _spec(shape, jnp.int32, one_chip)
+    done = {}
+
+    def get(shapes, name):
+        if (shapes, name) in done:
+            return done[shapes, name]
+        e = _engine_config(shapes)
+        decode, prefill = _build_step_fns(cfg, e, None)[:2]
+        params = place(jax.eval_shape(lambda k: T.init_params(cfg, k),
+                                      jax.random.PRNGKey(0)))
+        pool = place(jax.eval_shape(lambda: T.init_paged_state(
+            cfg, e.num_blocks, e.block_size, max_slots=e.max_slots)))
+        B, P = e.max_slots, e.max_blocks_per_seq
+        if name == "decode":
+            low = decode.lower(params, pool, i32(B), i32(B, P), i32(B),
+                               _spec((B,), jnp.bool_, one_chip))
+        else:
+            g, c = e.prefills_per_step, e.prefill_chunk
+            low = prefill.lower(params, pool, i32(g, c), i32(B, P), i32(g),
+                                i32(g), i32(g))
+        done[shapes, name] = low.compile()
+        return done[shapes, name]
+
+    return get
+
+
+@pytest.mark.parametrize("shapes", ["smoke", "offline"])
+def test_phi4_decode_step_fits_one_chip(engine_program, shapes):
+    compiled = engine_program(shapes, "decode")
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < HBM, f"{used / 2**30:.2f} GiB does not fit one chip"
+
+
+def _pool_movers(hlo_text, layer_elems):
+    """Copies, slices and slice updates, fused ones included, that move a
+    whole layer of a K/V pool leaf or more: a shape ending in (Hkv, hd)
+    with at least one layer's elements. Scatters of the new tokens and
+    gathers of a table's blocks are not among them."""
+    found = []
+    for shape, op in INSTRUCTION.findall(hlo_text):
+        dims = [int(d) for d in shape.split(",") if d]
+        if (op in ("copy", "dynamic-slice", "dynamic-update-slice")
+                and dims[-2:] == [HKV, HD]
+                and functools.reduce(lambda a, b: a * b, dims) >= layer_elems):
+            found.append(f"{op} {dims}")
+    return found
+
+
+@pytest.mark.parametrize("name,temp_gib", [("decode", 0.5), ("prefill", 1.07)])
+def test_engine_writes_pool_in_place(engine_program, name, temp_gib):
+    """The layer scan carries the pool and writes only the new tokens: no
+    instruction copies, slices or rewrites a layer of it, and the
+    temporaries hold no second pool (3.00 GiB decode, 3.57 GiB prefill
+    when the pool was a scanned input). Offline shapes: 1536 blocks."""
+    compiled = engine_program("offline", name)
+    layer_elems = OFFLINE["num_blocks"] * OFFLINE["block_size"] * HKV * HD
+    assert not _pool_movers(compiled.as_text(), layer_elems)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < temp_gib * GiB, f"{name} temp {temp / GiB:.2f} GiB"
 
 
 @pytest.mark.parametrize("mode", ["sharded", "paper"])
