@@ -2,7 +2,8 @@
 compile counting, and the result line.
 
 A cell is found by its name in ``BENCHMARK.json``. Its configuration file
-is ``perfbench/configs/<config>.json`` and its traffic file
+is ``perfbench/configs/<config>.json``, which names its architecture
+(``perfbench/arch/<arch>.py``), and its traffic file
 ``perfbench/traffic/<traffic>.json``; the traffic file names the driver
 (``perfbench/drivers/<driver>.py``), and each per-layer metric is read by
 ``perfbench/metrics/<metric>.py`` or, for a metric named ``<base>.<part>``,
@@ -54,6 +55,13 @@ def load_cell(name: str) -> dict:
         return "workloads" not in m or name in m["workloads"]
 
     cell["spec"] = _load_json("configs", f"{cell['config']}.json")
+    where = f"perfbench/configs/{cell['config']}.json"
+    arch = cell["spec"].get("arch")
+    if not arch:
+        raise BenchError(f"{where} names no 'arch'")
+    if not os.path.exists(os.path.join(HERE, "arch", f"{arch}.py")):
+        raise BenchError(f"{where} names arch {arch!r}: there is no "
+                         f"perfbench/arch/{arch}.py")
     cell["traffic"] = _load_json("traffic", f"{cell['traffic']}.json")
     cell["end_to_end"] = [m for m in man["end_to_end"] if reports(m)]
     moved = {m["name"] for m in cell["end_to_end"]}

@@ -4,17 +4,23 @@ seeded random tokens.
 Set-up builds one object, the step with its state, as ``launch/train.py``
 builds it: a ("data", "model") mesh over the cell's chips, the traffic
 file's plan, the state made inside one jit with the plan's shardings (the
-weights by ``perfbench.model`` from the seed, Adam's f32 master and
-moments beside them) and ``trainer.jit_train_step``. The first
+weights by the configuration's architecture module from the seed, Adam's
+f32 master and moments beside them) and ``trainer.jit_train_step``. The first
 ``CHECKED`` steps run through that same object and feed and are read for
 the comparison; the window then goes on with the same object.
 
-End to end: ``train_tok_s``, the tokens of every step from the window's
-opening to the end of the first step that completes ``seconds`` later,
-over that time. Each step ends in ``block_until_ready`` of its loss.
+End to end: ``train_tok_s``, the tokens of every step dispatched from the
+window's opening until ``seconds`` later, over the time from the opening
+until the last of them has completed. The window keeps ``AHEAD_S`` seconds
+of steps (a tenth of the window at most) in flight beyond the one it waits
+for, so that a pause of the host does not leave the chip idle; it reads
+no loss. When its time is up it dispatches nothing more, waits for every
+step it dispatched, and reads the clock after that wait.
 """
 from __future__ import annotations
 
+import collections
+import math
 import time
 
 import numpy as np
@@ -22,6 +28,7 @@ import numpy as np
 from perfbench import bench, model, serving
 
 CHECKED = 3
+AHEAD_S = 5.0
 
 
 def optimizer(traffic):
@@ -44,7 +51,7 @@ def build(cfg, s, traffic, devices):
     plan = par.make_plan(traffic["plan"], mesh)
 
     def init_state(key):
-        params = model.params_from_key(s, key)
+        params = model.arch(s).params_from_key(s, key)
         return {"params": params, "opt": opt.init(params)}
 
     state_abs = jax.eval_shape(init_state, jax.random.PRNGKey(0))
@@ -92,7 +99,7 @@ def reader(s, beta1):
 
     @jax.jit
     def norms(state, key):
-        init = model.params_from_key(s, key)
+        init = model.arch(s).params_from_key(s, key)
         grad = jax.tree.map(lambda m: m / (1.0 - beta1), state["opt"]["m"])
         delta = jax.tree.map(lambda w, p: w - p.astype(jnp.float32),
                              state["opt"]["master"], init)
@@ -109,8 +116,8 @@ def run(ctx):
     import jax
     traffic = ctx.cell["traffic"]
     spec = ctx.cell["spec"]
-    s = model.sizes(spec)
-    cfg = model.model_config(spec)
+    arch = model.arch(spec)
+    s, cfg = arch.sizes(spec), arch.model_config(spec)
     key = model.seed_key(ctx.seed)
     t0 = time.perf_counter()
     init, step, _, _ = build(cfg, s, traffic, ctx.devs)
@@ -120,11 +127,14 @@ def run(ctx):
     read = reader(s, traffic["optimizer"]["beta1"])
     losses, grad_norms = [], None
     for i in range(CHECKED):
+        t_step = time.perf_counter()
         state, m = step(state, batch(key, i))
         losses.append(float(m["loss"]))
+        step_s = time.perf_counter() - t_step
         if i == 0:
             grad_norms, _ = read(state, key)
     _, delta_norms = read(state, key)
+    ahead = max(1, math.ceil(min(AHEAD_S, ctx.seconds / 10) / step_s))
     t2 = time.perf_counter()
     i = CHECKED
     t_open = ctx.open_window()
@@ -132,14 +142,18 @@ def run(ctx):
     steps = 0
 
     def loop(until):
+        """Dispatch steps until ``until`` with ``ahead`` of them in flight
+        beyond the one waited for, then wait for all of them."""
         nonlocal state, i, steps
-        while True:
+        flight = collections.deque()
+        while time.perf_counter() < until:
             state, m = step(state, batch(key, i))
-            jax.block_until_ready(m["loss"])
+            flight.append(m["loss"])
             i += 1
             steps += 1
-            if time.perf_counter() >= until:
-                return
+            if len(flight) > ahead:
+                jax.block_until_ready(flight.popleft())
+        jax.block_until_ready(list(flight))
 
     cap = work = None
     if ctx.trace:
@@ -156,7 +170,7 @@ def run(ctx):
     summary = cap.reduce() if cap is not None else None
     tokens = steps * traffic["batch"] * traffic["seq"]
     ctx.log(f"train: {steps} steps, {tokens} tokens in {t_end - t_open:.3f} "
-            f"s; losses of the checked steps {losses}")
+            f"s, {ahead} in flight; losses of the checked steps {losses}")
     e2e = {"train_tok_s": tokens / (t_end - t_open)}
     obs = {"sizes": s, "trace": summary, "kind": ctx.devs[0].device_kind,
            "train_tokens": None if work is None else

@@ -2,54 +2,32 @@
 
 Counts are of the model's arithmetic (a multiply-add is 2 operations), never
 of what an implementation happens to do: a padded slot, a masked key or a
-recomputed layer adds nothing. ``s`` is ``perfbench.model.sizes``.
+recomputed layer adds nothing. These are the pieces of one layer; an
+architecture module (``perfbench/arch/``) sums them over its own layers.
 """
 from __future__ import annotations
 
 
-def _layer_matmul(s) -> int:
-    """Weights one token multiplies in one layer."""
-    d, hd, ff = s["d"], s["head_dim"], s["ff"]
-    return d * (s["heads"] + 2 * s["kv_heads"]) * hd + s["heads"] * hd * d \
-        + 3 * d * ff
+def attention_flops(heads: int, head_dim: int, keys) -> int:
+    """Queries attending ``keys`` keys in all, in one layer: q.k and p.v."""
+    return 4 * heads * head_dim * keys
 
 
-def attention_flops(s, context: int) -> int:
-    """One query attending ``context`` keys in every layer: q.k and p.v."""
-    return 4 * s["layers"] * s["heads"] * s["head_dim"] * context
+def causal_keys(start: int, valid: int, window: int | None = None) -> int:
+    """Keys that ``valid`` tokens from position ``start`` attend causally,
+    each at most ``window`` of them where a window is given."""
+    lo, hi = start + 1, start + valid          # a token at p sees p + 1 keys
+    if window is None or hi <= window:
+        return (lo + hi) * valid // 2
+    below = max(0, window - lo)                # tokens that see fewer
+    return (lo + window - 1) * below // 2 + window * (valid - below)
 
 
-def token_flops(s, context: int, logits: bool = True) -> int:
-    """Forward operations of one token that attends ``context`` keys; with
-    ``logits`` it also goes through the output head."""
-    f = 2 * s["layers"] * _layer_matmul(s) + attention_flops(s, context)
-    if logits:
-        f += 2 * s["d"] * s["vocab"]
-    return f
-
-
-def prefill_flops(s, start: int, valid: int) -> int:
-    """A chunk of ``valid`` prompt tokens from position ``start``: every
-    token through the layers, causally, and the last through the head."""
-    keys = valid * start + valid * (valid + 1) // 2
-    return (valid * 2 * s["layers"] * _layer_matmul(s)
-            + attention_flops(s, keys) + 2 * s["d"] * s["vocab"])
-
-
-def train_flops_per_token(s, seq: int) -> float:
-    """Forward and backward (3x forward) of one token of a causal sequence
-    of ``seq``: the matrices, the head, and attention over the
-    ``(seq + 1) / 2`` keys a token sees on average."""
-    return 3 * (2 * s["layers"] * _layer_matmul(s) + 2 * s["d"] * s["vocab"]
-                + attention_flops(s, (seq + 1) / 2))
-
-
-def paged_attention_work(s, lens, kv_bytes: int = 2):
-    """(operations, bytes) of one decode step of the paged kernel over all
-    layers: each live sequence of ``lens`` tokens reads its K and V, one
-    query and one output per head."""
-    ops = sum(attention_flops(s, n) for n in lens)
-    hd = s["head_dim"]
-    kv = sum(2 * n * s["kv_heads"] * hd * kv_bytes for n in lens)
-    qo = len(lens) * 2 * s["heads"] * hd * kv_bytes
-    return ops, s["layers"] * (kv + qo)
+def decode_attention_work(heads: int, kv_heads: int, head_dim: int, lens,
+                          kv_bytes: int = 2):
+    """(operations, bytes) of one layer of a decode step: each live sequence
+    of ``lens`` keys reads its K and V, one query and one output per head."""
+    ops = sum(attention_flops(heads, head_dim, n) for n in lens)
+    kv = sum(2 * n * kv_heads * head_dim * kv_bytes for n in lens)
+    qo = len(lens) * 2 * heads * head_dim * kv_bytes
+    return ops, kv + qo

@@ -2,13 +2,13 @@
 
 Least time = the larger of operations / peak bf16 rate and bytes / HBM
 bandwidth, with the work counted from the live lengths of every decode
-step (``flops.paged_attention_work``), never from the kernel's grid; over
-the summed device time of the kernel's ops. Decode steps here are bound by
+step (the architecture's ``paged_attention_work``), never from the
+kernel's grid; over the summed device time of the kernel's ops. Decode steps here are bound by
 bytes: a query per head against each live token's K and V.
 """
 import re
 
-from perfbench import flops, peaks
+from perfbench import model, peaks
 
 KERNEL = re.compile(r"^%?paged_attention(\.\d+)?$")
 
@@ -25,9 +25,11 @@ def read(obs, name):
     if t <= 0:
         return None
     pk = peaks.peaks(obs["kind"])
+    s = obs["sizes"]
+    work = model.arch(s).paged_attention_work
     ops = bytes_ = 0
     for lens in steps:
-        o, b = flops.paged_attention_work(obs["sizes"], lens)
+        o, b = work(s, lens)
         ops += o
         bytes_ += b
     least = max(ops / pk["bf16_flops"], bytes_ / pk["hbm_bytes_s"])

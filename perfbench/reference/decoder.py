@@ -149,7 +149,7 @@ def served_gaps(spec, seed, prompts, served, *, control=False):
     they agree). With ``control``, also the same gap for the token the fp8
     control (fp8) puts first there. Returns {"served": [...], "control": [...]},
     one float32 array per request."""
-    s = model.sizes(spec)
+    s = model.arch(spec).sizes(spec)
     weights, top, embed, run_layer, logits = _programs(
         tuple(sorted(s.items())))
     key = model.seed_key(seed)
@@ -261,7 +261,7 @@ def train_readings(spec, seed, traffic, steps, batch, *, quant=False):
     ``batch(i)`` gives: each step's loss, the per-leaf norms of the first
     (clipped) gradient, and of the weights' change after the last step.
     Leaf names and per-layer arrays follow the program's layout."""
-    s = model.sizes(spec)
+    s = model.arch(spec).sizes(spec)
     init, grad, adam, norms = _train_programs(
         tuple(sorted(s.items())), quant,
         tuple(sorted(traffic["optimizer"].items())))

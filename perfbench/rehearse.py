@@ -54,8 +54,8 @@ def main(argv=None):
     dev = SingleDeviceSharding(chip)
     cell = bench.load_cell(name)
     spec, traffic = cell["spec"], cell["traffic"]
-    cfg = model.model_config(spec)
-    s = model.sizes(spec)
+    arch = model.arch(spec)
+    s, cfg = arch.sizes(spec), arch.model_config(spec)
     place = lambda tree: jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=dev), tree)
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=dev)

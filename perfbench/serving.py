@@ -158,8 +158,8 @@ def build(cell, seed):
     import jax
     from repro.serving.engine import Engine, EngineConfig
     spec = cell["spec"]
-    s = model.sizes(spec)
-    cfg = model.model_config(spec)
+    arch = model.arch(spec)
+    s, cfg = arch.sizes(spec), arch.model_config(spec)
     t0 = time.perf_counter()
     params = jax.block_until_ready(model.program_params(s, seed))
     t1 = time.perf_counter()

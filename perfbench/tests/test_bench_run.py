@@ -78,6 +78,37 @@ def test_driver_runs_to_its_result_line(monkeypatch, capsys, driver):
         assert c["value"] <= c["limit"]
 
 
+def test_train_window_counts_every_step_it_sent(monkeypatch, capsys):
+    """The train window keeps steps in flight and closes only after every
+    step it dispatched has completed: at the close none is still being
+    computed, each counts, and the rate's time spans at least the
+    window's seconds."""
+    sent, ready = [], []
+    build = train_steps.build
+
+    def counted(cfg, s, traffic, devices):
+        init, step, state_abs, batch_abs = build(cfg, s, traffic, devices)
+
+        def step_counted(state, batch):
+            state, m = step(state, batch)
+            sent.append(m["loss"])
+            return state, m
+        return init, step_counted, state_abs, batch_abs
+    close = R.Run.close_window
+
+    def close_window(self):
+        ready.append(all(x.is_ready() for x in sent))
+        close(self)
+    monkeypatch.setattr(train_steps, "build", counted)
+    monkeypatch.setattr(R.Run, "close_window", close_window)
+    out = _run(monkeypatch, capsys, "train_steps", seconds=2)
+    assert ready == [True]
+    assert len(sent) == out["attempted"] + train_steps.CHECKED
+    t = tiny.cell("train_steps")["traffic"]
+    rate = out["metrics"]["train_tok_s"]["value"]
+    assert out["attempted"] * t["batch"] * t["seq"] / rate >= 2
+
+
 def test_altered_token_is_caught(monkeypatch, capsys):
     """A token altered where the decode step produces it."""
     build = serving.build

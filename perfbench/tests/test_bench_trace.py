@@ -75,7 +75,8 @@ def test_idle_share_reader():
 def test_kernel_roofline_reader():
     s = trace.summarize(OPS, MODULES, HOST, WINDOW)
     roof = bench.load_module("metrics", "paged_attn_roofline")
-    sizes = {"layers": 2, "heads": 4, "kv_heads": 2, "head_dim": 8}
+    sizes = {"arch": "decoder", "layers": 2, "heads": 4, "kv_heads": 2,
+             "head_dim": 8}
     obs = {"trace": s, "kind": "TPU v5 lite", "sizes": sizes,
            "decode_lens": [[10, 20], [30]]}
     # bytes: 2 layers x (K and V of 60 tokens: 2*60*2*8*2 + q and out of

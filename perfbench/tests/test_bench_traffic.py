@@ -59,9 +59,9 @@ def test_another_order_seed_offers_the_same_work_in_another_order():
 
 
 def test_weights_one_layer_alone_equal_the_stacked_ones():
-    s = {"layers": 3, "d": 16, "heads": 2, "kv_heads": 1, "head_dim": 8,
-         "ff": 32, "vocab": 50, "eps": 1e-5, "theta": 1e4, "tied": False,
-         "dtype": "bfloat16"}
+    s = {"arch": "decoder", "layers": 3, "d": 16, "heads": 2, "kv_heads": 1,
+         "head_dim": 8, "ff": 32, "vocab": 50, "eps": 1e-5, "theta": 1e4,
+         "tied": False, "dtype": "bfloat16"}
     p = model.program_params(s, BIG)
     key = model.seed_key(BIG)
     for i in range(3):
@@ -82,7 +82,8 @@ def test_weights_follow_the_programs_layout():
     spec = dict(model.load_spec("phi4mini"), num_hidden_layers=2,
                 hidden_size=64, intermediate_size=128, num_attention_heads=4,
                 num_key_value_heads=2, vocab_size=256)
-    s, cfg = model.sizes(spec), model.model_config(spec)
+    arch = model.arch(spec)
+    s, cfg = arch.sizes(spec), arch.model_config(spec)
     ours = jax.eval_shape(lambda: model.program_params(s, 0))
     theirs = jax.eval_shape(lambda k: T.init_params(cfg, k),
                             jax.random.PRNGKey(0))

@@ -8,7 +8,8 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 
 SPEC = {
-    "name": "tiny", "registry": "phi4-mini-3.8b", "reference": "decoder",
+    "name": "tiny", "arch": "decoder", "registry": "phi4-mini-3.8b",
+    "reference": "decoder",
     "hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
     "num_attention_heads": 4, "num_key_value_heads": 2, "vocab_size": 256,
     "rms_norm_eps": 1e-5, "rope_theta": 10000.0,
